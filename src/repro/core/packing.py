@@ -34,12 +34,15 @@ byte inflow (a character must never claim bytes from the next document).
 
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple, Sequence
 
 import numpy as np
 
 import jax
 import jax.numpy as jnp
+
+from repro import spans
 
 # One VMEM tile of the fused/ragged kernels: 8 sublanes x 128 lanes.
 TILE = 1024
@@ -61,6 +64,8 @@ def _round_up(n: int, block: int) -> int:
     return -(-n // block) * block
 
 
+@functools.partial(jax.profiler.annotate_function,
+                   name=spans.PACK_DOCUMENTS)
 def pack_documents(docs: Sequence, *, dtype=None, block: int = TILE,
                    doc_tiles: int | None = None,
                    pad_to_docs: int | None = None) -> PackedDocs:

@@ -57,6 +57,7 @@ ASCII-pure chunks (the paper's Latin benchmark) reduce to a widening copy.
 
 from __future__ import annotations
 
+import functools
 import warnings
 
 import numpy as np
@@ -64,6 +65,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro import spans
 from repro.core import compaction, latin1 as l1mod, result as R
 from repro.core import utf16 as u16mod, utf32 as u32mod, utf8 as u8mod
 from repro.core.result import STATUS_OK, TranscodeResult  # noqa: F401  (re-export)
@@ -577,6 +579,7 @@ def cps_to_utf32le_bytes(cp):
 _WINDOWED_PAIRS = {("utf8", "utf16"), ("utf16", "utf8")}
 
 
+@functools.partial(jax.profiler.annotate_function, name=spans.TRANSCODE)
 def transcode(src, dst_format, *, src_format: str = "utf8", n_valid=None,
               strategy: str = DEFAULT_STRATEGY, validate: bool = True,
               errors: str = "strict"):
@@ -644,6 +647,8 @@ def transcode_utf16_to_utf8(u, n_valid=None, *, strategy: str = DEFAULT_STRATEGY
 # Ragged packed-batch entry points (one Pallas launch per batch).
 
 
+@functools.partial(jax.profiler.annotate_function,
+                   name=spans.RAGGED_TRANSCODE)
 def ragged_transcode(data, offsets, lengths, *, src_format: str = "utf8",
                      dst_format: str = "utf16", validate: bool = True,
                      errors: str = "strict",

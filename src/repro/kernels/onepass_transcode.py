@@ -62,6 +62,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import spans
 from repro.core import result as R
 from repro.kernels import fused_transcode as ft
 from repro.kernels import runtime
@@ -82,6 +83,9 @@ _check_errors = R.check_errors_policy
 #   [1] sticky error flag      (max over tiles)
 #   [2] sticky first-error     (min over tiles; _IMAX = clean)
 _CARRY = 3
+
+# The launch's name, in the profiler trace of the chip as in the HLO.
+KERNEL_NAME = "onepass_transcode"
 
 
 def _onepass_kernel(n_ref, xp_ref, x_ref, xn_ref, out_ref, fin_ref, buf,
@@ -128,17 +132,19 @@ def _onepass_call(xm, n, src, dst, errors, validate, ascii_skip, interpret):
     # data-dependent base (ft.store_window); the (2,) SMEM vector is the
     # final (count, status) pair off the carry.
     out_spec, out_shape, scratch = ft.window_out(nblk, width, codec_d.dtype)
-    outp, fin = pl.pallas_call(
-        kernel,
-        grid=(nblk,),
-        in_specs=[ft._SMEM, ft._tile_spec(0), ft._tile_spec(1),
-                  ft._tile_spec(2)],
-        out_specs=[out_spec, ft._SMEM],
-        out_shape=[out_shape, jax.ShapeDtypeStruct((2,), jnp.int32)],
-        scratch_shapes=scratch + [pltpu.SMEM((_CARRY,), jnp.int32)],
-        compiler_params=ft._SEQUENTIAL,
-        interpret=interpret,
-    )(n1, x3, x3, x3)
+    with jax.named_scope(KERNEL_NAME):
+        outp, fin = pl.pallas_call(
+            kernel,
+            grid=(nblk,),
+            in_specs=[ft._SMEM, ft._tile_spec(0), ft._tile_spec(1),
+                      ft._tile_spec(2)],
+            out_specs=[out_spec, ft._SMEM],
+            out_shape=[out_shape, jax.ShapeDtypeStruct((2,), jnp.int32)],
+            scratch_shapes=scratch + [pltpu.SMEM((_CARRY,), jnp.int32)],
+            compiler_params=ft._SEQUENTIAL,
+            interpret=interpret,
+            name=KERNEL_NAME,
+        )(n1, x3, x3, x3)
     return outp.reshape(-1), fin
 
 
@@ -191,14 +197,15 @@ def transcode_onepass(x, n_valid=None, *, src: str, dst: str,
     _check_errors(errors)
     faults.fire(faults.KERNEL_ONEPASS)   # chaos-suite hook (no-op in prod)
     codec_s, _codec_d, _f = stages.get_pair(src, dst)
-    x = jnp.asarray(x)
-    if x.dtype != codec_s.dtype:
-        x = x.astype(codec_s.dtype)
-    n = x.shape[0] if n_valid is None else n_valid
-    return _transcode_impl(
-        x, jnp.asarray(n, jnp.int32), src, dst, validate,
-        runtime.resolve_interpret(interpret), ascii_fastpath,
-        n_valid is not None, errors)
+    with jax.profiler.TraceAnnotation(spans.TRANSCODE_DISPATCH):
+        x = jnp.asarray(x)
+        if x.dtype != codec_s.dtype:
+            x = x.astype(codec_s.dtype)
+        n = x.shape[0] if n_valid is None else n_valid
+        return _transcode_impl(
+            x, jnp.asarray(n, jnp.int32), src, dst, validate,
+            runtime.resolve_interpret(interpret), ascii_fastpath,
+            n_valid is not None, errors)
 
 
 def scan_onepass(x, n_valid=None, *, src: str, dst: str, interpret=None):

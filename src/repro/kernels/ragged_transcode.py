@@ -74,6 +74,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import spans
 from repro.core import compaction, packing
 from repro.core import result as R
 from repro.kernels import fused_transcode as ft
@@ -92,6 +93,10 @@ _SMEM = ft._SMEM
 _tile_spec = ft._tile_spec
 
 _check_errors = R.check_errors_policy
+
+# The single-pass launch's name, in the profiler trace of the chip as in
+# the HLO.
+ONEPASS_KERNEL_NAME = "ragged_onepass_transcode"
 
 
 def _nblk(total: int) -> int:
@@ -262,16 +267,18 @@ def _ragged_onepass_impl(data, offsets, lengths, src, dst, validate,
                                ascii_skip=True)
     per_tile = jax.ShapeDtypeStruct((nblk,), jnp.int32)
     out_spec, out_shape, scratch = ft.window_out(nblk, width, codec_d.dtype)
-    outp, totals, errs, ferrs = pl.pallas_call(
-        kernel,
-        grid=(nblk,),
-        in_specs=_OWNED_SPECS + _TILE_SPECS,
-        out_specs=[out_spec, _SMEM, _SMEM, _SMEM],
-        out_shape=[out_shape, per_tile, per_tile, per_tile],
-        scratch_shapes=scratch + [pltpu.SMEM((1,), jnp.int32)],
-        compiler_params=ft._SEQUENTIAL,
-        interpret=interpret,
-    )(*owned, d3, d3, d3)
+    with jax.named_scope(ONEPASS_KERNEL_NAME):
+        outp, totals, errs, ferrs = pl.pallas_call(
+            kernel,
+            grid=(nblk,),
+            in_specs=_OWNED_SPECS + _TILE_SPECS,
+            out_specs=[out_spec, _SMEM, _SMEM, _SMEM],
+            out_shape=[out_shape, per_tile, per_tile, per_tile],
+            scratch_shapes=scratch + [pltpu.SMEM((1,), jnp.int32)],
+            compiler_params=ft._SEQUENTIAL,
+            interpret=interpret,
+            name=ONEPASS_KERNEL_NAME,
+        )(*owned, d3, d3, d3)
     outp = outp.reshape(-1)
     total = jnp.sum(totals)
     cap = factor * nblk * BLOCK
@@ -323,6 +330,8 @@ def _ragged_scan_impl(data, offsets, lengths, src, dst, interpret):
     return counts, statuses
 
 
+@functools.partial(jax.profiler.annotate_function,
+                   name=spans.RAGGED_CHECK_LAYOUT)
 def _as_packed(data, offsets, lengths, dtype):
     data = jnp.asarray(data)
     if data.dtype != dtype:
@@ -385,17 +394,14 @@ def transcode_ragged(data, offsets, lengths, *, src: str, dst: str,
     codec_s, _codec_d, _f = stages.get_pair(src, dst)
     data, offsets, lengths = _as_packed(data, offsets, lengths,
                                         codec_s.dtype)
-    if strategy == "onepass":
-        return _ragged_onepass_impl(data, offsets, lengths, src, dst,
-                                    validate,
-                                    runtime.resolve_interpret(interpret),
-                                    errors)
-    if strategy != "fused":
+    if strategy not in ("onepass", "fused"):
         raise ValueError(
             f"transcode_ragged: unknown strategy {strategy!r} "
             f"(expected 'onepass' or 'fused')")
-    return _ragged_impl(data, offsets, lengths, src, dst, validate,
-                        runtime.resolve_interpret(interpret), errors)
+    impl = _ragged_onepass_impl if strategy == "onepass" else _ragged_impl
+    with jax.profiler.TraceAnnotation(spans.RAGGED_DISPATCH):
+        return impl(data, offsets, lengths, src, dst, validate,
+                    runtime.resolve_interpret(interpret), errors)
 
 
 def scan_ragged(data, offsets, lengths, *, src: str, dst: str,
