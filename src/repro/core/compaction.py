@@ -76,34 +76,40 @@ def tile_exclusive_scan(x: jax.Array, rows: int = 8):
     """Row-major exclusive prefix sum of a VMEM tile, without ``cumsum``.
 
     ``x`` is an int32 tile of shape ``(rows, lanes)`` (or flat, viewed as
-    ``(rows, -1)``).  Mosaic has no scan primitive, so the prefix is a
+    ``(rows, -1)``), or a ``(G, rows, lanes)`` block of G tiles scanned
+    each on its own.  Mosaic has no scan primitive, so the prefix is a
     Hillis-Steele log-step scan along the lanes (``log2(lanes)`` lane
     rotations, adds and selects) plus the same over the row totals
     across sublanes — integer adds only, so it is exact.
 
     Returns ``(exclusive, total)``: the exclusive prefix (same shape as
-    ``x``) and the scalar tile total.  Runs inside Pallas kernels (the
-    ``jit`` only lets it also run eagerly: ``pltpu.roll`` has no eager
-    rule).
+    ``x``) and the tile total (a scalar for one tile; ``(G, 1, 1)`` for
+    a block).  Runs inside Pallas kernels (the ``jit`` only lets it also
+    run eagerly: ``pltpu.roll`` has no eager rule).
     """
     flat = x.ndim == 1
     x2 = x.reshape(rows, -1) if flat else x
-    nrow, nlane = x2.shape
-    row = jax.lax.broadcasted_iota(jnp.int32, x2.shape, 0)
-    lane = jax.lax.broadcasted_iota(jnp.int32, x2.shape, 1)
+    ra, la = x2.ndim - 2, x2.ndim - 1
+    nrow, nlane = x2.shape[ra], x2.shape[la]
+    row = jax.lax.broadcasted_iota(jnp.int32, x2.shape, ra)
+    lane = jax.lax.broadcasted_iota(jnp.int32, x2.shape, la)
     incl = x2
     s = 1
     while s < nlane:
-        incl = incl + jnp.where(lane >= s, pltpu.roll(incl, s, 1), 0)
+        incl = incl + jnp.where(lane >= s, pltpu.roll(incl, s, la), 0)
         s <<= 1
-    tot_r = jnp.broadcast_to(jnp.sum(x2, axis=1, keepdims=True), x2.shape)
+    tot_r = jnp.broadcast_to(jnp.sum(x2, axis=la, keepdims=True), x2.shape)
     above = tot_r
     s = 1
     while s < nrow:
-        above = above + jnp.where(row >= s, pltpu.roll(above, s, 0), 0)
+        above = above + jnp.where(row >= s, pltpu.roll(above, s, ra), 0)
         s <<= 1
     excl = incl + (above - tot_r) - x2
-    return (excl.reshape(x.shape) if flat else excl), jnp.sum(x2)
+    if x2.ndim == 2:
+        total = jnp.sum(x2)
+    else:
+        total = (excl + x2)[..., nrow - 1:, nlane - 1:]
+    return (excl.reshape(x.shape) if flat else excl), total
 
 
 def tile_base_offsets(tile_totals: jax.Array):

@@ -80,12 +80,16 @@ def _tile(x):
 
 
 def _gidx(shape):
-    """Global stream index of every lane in the current ``(ROWS, LANES)``
-    tile."""
+    """Global stream index of every lane of the current ``(ROWS, LANES)``
+    tile, or of the current ``(G, ROWS, LANES)`` block of tiles."""
     i = pl.program_id(0)
-    row = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
-    lane = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
-    return i * BLOCK + row * LANES + lane
+    row = jax.lax.broadcasted_iota(jnp.int32, shape[-2:], 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, shape[-2:], 1)
+    pos = row * LANES + lane
+    if len(shape) == 2:
+        return i * BLOCK + pos
+    step = i * (shape[0] * BLOCK)
+    return jnp.stack([step + g * BLOCK + pos for g in range(shape[0])])
 
 
 _check_errors = R.check_errors_policy
@@ -151,14 +155,19 @@ def check_launch(nblk: int, factor: int, per_tile_arrays: int,
 #      VMEM: ``pend``) under the window's first chunk,
 #   3. DMAs the window's chunks to HBM and waits, and
 #   4. keeps the chunk holding its last unit as the next ``pend``.
-# Only whole chunks move, at chunk-aligned HBM offsets.
+# Only whole chunks move, at chunk-aligned HBM offsets.  A step that
+# handles a block of G tiles merges their G windows into one VMEM buffer
+# first — tile g's window at ``base + prefix_g``, ``prefix_g`` the sum
+# of the totals of the tiles before it, each merged (1-2) under the
+# chunk the tile before it left partially filled — and then makes ONE
+# copy of the whole buffer (3) and keeps one ``pend`` (4).
 
 
-def window_out(nblk: int, width: int, dtype):
+def window_out(nblk: int, width: int, dtype, tiles_per_step: int = 1):
     """``(out_spec, out_shape, scratch_shapes)`` of a windowed writer.  A
-    step's ``width`` units, starting anywhere inside a chunk, touch ``k``
-    chunks."""
-    k = width // BLOCK + 1
+    step's ``tiles_per_step * width`` units, starting anywhere inside a
+    chunk, touch ``k`` chunks."""
+    k = tiles_per_step * width // BLOCK + 1
     nchunk = nblk * width // BLOCK + 1
     return (pl.BlockSpec(memory_space=pl.ANY),
             jax.ShapeDtypeStruct((nchunk, ROWS, LANES), dtype),
@@ -170,28 +179,45 @@ def window_out(nblk: int, width: int, dtype):
 def store_window(stage, base, tot, out_ref, buf, pend, sem):
     """Store one tile's compact window (``stage``: ``(width // LANES,
     LANES)`` int32, ``tot`` units at its front) at output offset
-    ``base``; see the section comment above."""
-    k = buf.shape[0]
+    ``base``; or, for a block, the G windows of ``stage``: ``(G, width //
+    LANES, LANES)`` with ``tot`` the tuple of their totals, one after
+    the other from ``base``.  See the section comment above."""
+    if stage.ndim == 2:
+        windows, tot = [stage], (tot,)
+    else:
+        windows = [stage[g] for g in range(stage.shape[0])]
 
     @pl.when(pl.program_id(0) == 0)
     def _():
         pend[...] = jnp.zeros(pend.shape, pend.dtype)
 
-    off = base % BLOCK
-    win = jnp.concatenate(
-        [stage, jnp.zeros((k * ROWS - stage.shape[0], LANES), jnp.int32)])
-    win = common.flat_roll(win, off)
-    head = win[:ROWS]
-    head = jnp.where(common.flat_index(head) < off,
-                     pend[...].astype(jnp.int32), head)
-    buf[0] = head.astype(buf.dtype)
-    for c in range(1, k):
-        buf[c] = win[c * ROWS:(c + 1) * ROWS].astype(buf.dtype)
+    # Each window spans its rows plus one chunk, for its start offset.
+    span = stage.shape[-2] // ROWS + 1
+    pos = off = base % BLOCK          # the next unit's place in ``buf``
+    below = pend[...]
+    for g, (w, t) in enumerate(zip(windows, tot)):
+        # The first window starts in chunk 0; the others in a chunk the
+        # data decides.
+        c = 0 if g == 0 else pos // BLOCK
+        at = pos if g == 0 else pos % BLOCK
+        win = jnp.concatenate(
+            [w, jnp.zeros((ROWS, LANES), jnp.int32)])
+        win = common.flat_roll(win, at)
+        head = win[:ROWS]
+        head = jnp.where(common.flat_index(head) < at,
+                         below.astype(jnp.int32), head)
+        buf[c] = head.astype(buf.dtype)
+        for j in range(1, span):
+            buf[c + j] = win[j * ROWS:(j + 1) * ROWS].astype(buf.dtype)
+        pos = pos + t
+        if g + 1 < len(tot):
+            below = buf[pos // BLOCK]
+    k = buf.shape[0]
     copy = pltpu.make_async_copy(buf, out_ref.at[pl.ds(base // BLOCK, k)],
                                  sem)
     copy.start()
     copy.wait()
-    pend[...] = buf[(off + tot) // BLOCK]
+    pend[...] = buf[pos // BLOCK]
 
 
 # ---------------------------------------------------------------------------

@@ -11,34 +11,47 @@ On TPU, Pallas grid steps execute sequentially per core, so that scan
 does not need a launch boundary at all: it is a scalar **carry in SMEM
 scratch** (DESIGN.md §9).
 
-Each grid step of the single launch:
+Each grid step of the single launch handles a block of G consecutive
+1 KiB tiles, shaped ``(G, ROWS, LANES)`` (G = :func:`tiles_per_step`:
+the largest power of two up to ``G_MAX`` that the input's tile count
+allows), and:
 
-  1. decodes/analyzes its VMEM tile ONCE (``stages.driver.decode_once``
-     — the same generic body the fused passes instantiate),
-  2. counts the tile's output units + fused validation scalars off the
-     decoded lanes (``count_decoded``),
+  1. decodes/analyzes the block ONCE (``stages.driver.decode_once`` —
+     the same generic body the fused passes instantiate; over a block
+     each tile is decoded as it would be alone, with its own
+     neighbours, through one shared routing network),
+  2. counts each tile's output units + the block's fused validation
+     scalars off the decoded lanes (``count_decoded``),
   3. reads the running output offset from the SMEM carry — the exclusive
-     scan, one scalar add per tile instead of an inter-launch cumsum —
-     and stores the compact stage window (``stage_decoded``, fed the
-     *already-decoded* tile) at that base,
-  4. advances the carry and folds the tile's error scalars into the
-     sticky (err_flag, first_error) carry; the final
-     ``(count, status)`` pair is emitted from the carry, so nothing
-     per-tile ever round-trips to the host.
+     scan, scalar adds instead of an inter-launch cumsum — and stores
+     the G compact stage windows (``stage_decoded``, fed the
+     *already-decoded* block) at that base plus each tile's prefix
+     inside the block, with one DMA (``fused_transcode.store_window``),
+  4. advances the carry by the block's total and folds the block's
+     error scalars into the sticky (err_flag, first_error) carry; the
+     final ``(count, status)`` pair is emitted from the carry, so
+     nothing per-tile ever round-trips to the host.
+
+A grid step costs a fixed amount on the chip besides its vector work
+(pipelining three input blocks, the branch dispatch, one DMA and its
+wait); a block pays it once for G tiles, and the G tiles' vector work
+forms G independent dependency chains.  A G=1 block is the one-tile
+step the ragged kernel still runs.
 
 The whole-buffer ASCII ``lax.cond`` of the two-pass wrappers additionally
-becomes a **per-tile three-way class dispatch** (paper Algorithm 3 at
-tile granularity plus the ≤2-byte class, ``stages.driver.onepass_tile``,
-DESIGN.md §9): a pure-ASCII tile with clean boundary inflow reduces to a
-widening copy, a tile whose every code point fits 11 bits takes the
+becomes a **per-block three-way class dispatch** (paper Algorithm 3 at
+block granularity plus the ≤2-byte class, ``stages.driver.onepass_tile``,
+DESIGN.md §9; a block takes a class only when each of its tiles
+would): a pure-ASCII block with clean boundary inflow reduces to a
+widening copy, a block whose every code point fits 11 bits takes the
 class-specialized ≤2-byte body (no 3-/4-unit assembly, no surrogate
-folding, fewer candidate planes to stage), and only genuinely wide tiles pay
-the general speculative decode.  Mostly-ASCII documents keep the copy
-path tile by tile, and dense 2-byte scripts ride the narrowed class
-instead of falling off it globally.  (The whole-buffer cond survives in
-front of the launch — when the entire buffer is ASCII, skipping the
-kernel dispatch outright is strictly cheaper than taking the skip tile
-by tile.)
+folding, fewer candidate planes to stage), and only blocks holding a
+wide character pay the general speculative decode.  Mostly-ASCII
+documents keep the copy path block by block, and dense 2-byte scripts
+ride the narrowed class instead of falling off it globally.  (The
+whole-buffer cond survives in front of the launch — when the entire
+buffer is ASCII, skipping the kernel dispatch outright is strictly
+cheaper than taking the skip block by block.)
 
 Results are bit-identical to ``strategy="fused"`` — (buffer, count,
 status) across every matrix cell × ``errors=`` policy (pinned by
@@ -87,6 +100,41 @@ _CARRY = 3
 # The launch's name, in the profiler trace of the chip as in the HLO.
 KERNEL_NAME = "onepass_transcode"
 
+# Most 1 KiB tiles one grid step handles (a power of two).  Chosen on the
+# chip: the smallest G within 3% of the best kernel time on 4 MiB UTF-8
+# -> UTF-16 calls of the general and the ≤2-byte tile class (PERF.md).
+G_MAX = 32
+
+
+def tiles_per_step(n: int) -> int:
+    """Tiles per grid step for an ``n``-element input: the largest power
+    of two up to ``G_MAX`` that does not exceed its tile count, so a
+    short input is never padded to a whole block."""
+    nblk = max(1, -(-n // BLOCK))
+    return min(G_MAX, 1 << (nblk.bit_length() - 1))
+
+
+def _block_specs(g: int):
+    """``(prev, cur, next)`` BlockSpecs over the block-aligned tiling of
+    ``runtime.tile_with_boundaries(..., block=g)``: the block of ``g``
+    tiles, and the single tiles just before and just after it."""
+    tile = (1, ROWS, LANES)
+    return [pl.BlockSpec(tile, lambda i: ((i + 1) * g - 1, 0, 0)),
+            pl.BlockSpec((g, ROWS, LANES), lambda i: (i + 1, 0, 0)),
+            pl.BlockSpec(tile, lambda i: ((i + 2) * g, 0, 0))]
+
+
+def _load_block(xp_ref, x_ref, xn_ref):
+    """``(x, xp, xn)`` int32 lanes of the step: one tile as the fused
+    kernels load it, or a ``(G, ROWS, LANES)`` block with each tile's
+    neighbours built from the block and the tiles around it."""
+    if x_ref.shape[0] == 1:
+        return ft._load(x_ref), ft._load(xp_ref), ft._load(xn_ref)
+    x = x_ref[...].astype(jnp.int32)
+    xp = jnp.concatenate([xp_ref[...].astype(jnp.int32), x[:-1]])
+    xn = jnp.concatenate([x[1:], xn_ref[...].astype(jnp.int32)])
+    return x, xp, xn
+
 
 def _onepass_kernel(n_ref, xp_ref, x_ref, xn_ref, out_ref, fin_ref, buf,
                     pend, sem, carry, *, src, dst, errors, validate,
@@ -100,16 +148,15 @@ def _onepass_kernel(n_ref, xp_ref, x_ref, xn_ref, out_ref, fin_ref, buf,
         carry[1] = 0
         carry[2] = _IMAX
 
-    x = ft._load(x_ref)
+    x, xp, xn = _load_block(xp_ref, x_ref, xn_ref)
     gidx = ft._gidx(x.shape)
     tot, err, ferr, stage = sdrv.onepass_tile(
-        codec_s, codec_d, x, ft._load(xp_ref), ft._load(xn_ref),
-        gidx < n_ref[0], gidx, errors=errors, validate=validate,
-        ascii_skip=ascii_skip)
+        codec_s, codec_d, x, xp, xn, gidx < n_ref[0], gidx, errors=errors,
+        validate=validate, ascii_skip=ascii_skip)
 
     base = carry[0]
     ft.store_window(stage, base, tot, out_ref, buf, pend, sem)
-    carry[0] = base + tot
+    carry[0] = base + (sum(tot) if isinstance(tot, tuple) else tot)
     carry[1] = jnp.maximum(carry[1], err)
     carry[2] = jnp.minimum(carry[2], ferr)
     # Written every step; the grid is sequential, so the last write is
@@ -122,8 +169,11 @@ def _onepass_call(xm, n, src, dst, errors, validate, ascii_skip, interpret):
     """The single launch: returns ``(out_window, (count, status))``."""
     codec_s, codec_d, factor = stages.get_pair(src, dst)
     width = stages.stage_width(codec_s, codec_d)
-    x3, nblk = ft._tile(xm)
-    ft.check_launch(nblk, factor, 0, interpret, "transcode")
+    # The limit counts the input's own tiles, not the block padding.
+    ft.check_launch(max(1, -(-xm.shape[0] // BLOCK)), factor, 0, interpret,
+                    "transcode")
+    g = tiles_per_step(xm.shape[0])
+    x3, nblk = runtime.tile_with_boundaries(xm, ROWS, LANES, block=g)
     n1 = jnp.asarray(n, jnp.int32).reshape(1)
     kernel = functools.partial(_onepass_kernel, src=src, dst=dst,
                                errors=errors, validate=validate,
@@ -131,13 +181,13 @@ def _onepass_call(xm, n, src, dst, errors, validate, ascii_skip, interpret):
     # The compact buffer is chunked HBM written by DMA at the carried,
     # data-dependent base (ft.store_window); the (2,) SMEM vector is the
     # final (count, status) pair off the carry.
-    out_spec, out_shape, scratch = ft.window_out(nblk, width, codec_d.dtype)
+    out_spec, out_shape, scratch = ft.window_out(nblk, width, codec_d.dtype,
+                                                 g)
     with jax.named_scope(KERNEL_NAME):
         outp, fin = pl.pallas_call(
             kernel,
-            grid=(nblk,),
-            in_specs=[ft._SMEM, ft._tile_spec(0), ft._tile_spec(1),
-                      ft._tile_spec(2)],
+            grid=(nblk // g,),
+            in_specs=[ft._SMEM] + _block_specs(g),
             out_specs=[out_spec, ft._SMEM],
             out_shape=[out_shape, jax.ShapeDtypeStruct((2,), jnp.int32)],
             scratch_shapes=scratch + [pltpu.SMEM((_CARRY,), jnp.int32)],
@@ -192,7 +242,7 @@ def transcode_onepass(x, n_valid=None, *, src: str, dst: str,
     SMEM carry across the sequential grid instead of an inter-launch
     cumsum, and the count/status come off the carry rather than an
     ``nblk``-vector round trip.  ``ascii_fastpath`` controls both the
-    whole-buffer cond and the per-tile ASCII skip.
+    whole-buffer cond and the per-block class dispatch.
     """
     _check_errors(errors)
     faults.fire(faults.KERNEL_ONEPASS)   # chaos-suite hook (no-op in prod)

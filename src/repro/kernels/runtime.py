@@ -54,19 +54,27 @@ def resolve_interpret(interpret) -> bool:
     return bool(interpret)
 
 
-def tile_with_boundaries(x, rows: int, lanes: int, boundary_tiles: int = 2):
+def tile_with_boundaries(x, rows: int, lanes: int, boundary_tiles: int = 2,
+                         block: int = 1):
     """Pad flat ``x`` (dtype preserved) to whole (rows, lanes) tiles and
     add zero boundary tiles: one leading tile for kernels that only look
     back (``boundary_tiles=1``), one on each end for kernels with
     prev/next BlockSpecs (``boundary_tiles=2``).  Returns ``(x3, nblk)``.
+
+    With ``block > 1`` (``boundary_tiles=2`` only) the tiles are padded
+    to whole blocks of ``block`` tiles and ``block`` zero tiles lead, so
+    that block ``i`` of the data is block ``i + 1`` of ``x3`` and the
+    tiles before and after it are single tiles of ``x3``; ``nblk`` counts
+    the padded tiles.
     """
-    block = rows * lanes
+    tile = rows * lanes
     n = x.shape[0]
-    nblk = max(1, -(-n // block))
-    pad = nblk * block - n
+    nblk = -(-max(1, -(-n // tile)) // block) * block
+    pad = nblk * tile - n
     x = jnp.concatenate([x, jnp.zeros((pad,), x.dtype)])
     x3 = x.reshape(nblk, rows, lanes)
     z = jnp.zeros((1, rows, lanes), x.dtype)
     if boundary_tiles == 1:
         return jnp.concatenate([z, x3], 0), nblk
-    return jnp.concatenate([z, x3, z], 0), nblk
+    lead = jnp.zeros((block, rows, lanes), x.dtype)
+    return jnp.concatenate([lead, x3, z], 0), nblk

@@ -1,8 +1,10 @@
 """Shared tile-layout helpers for the codec stages.
 
 Every stage body sees its VMEM tile as a 2-D ``(ROWS, LANES)`` int32
-array (any leading axes are carried along) plus the two neighbour tiles,
-and reads it as the row-major flat element stream.  The helpers below are
+array plus the two neighbour tiles, and reads it as the row-major flat
+element stream.  A leading axis is carried along: a ``(G, ROWS, LANES)``
+block is G independent tiles, each with its own neighbours, and every
+helper below treats each tile as it treats a lone one.  The helpers are
 the single definition of that convention, written only with operations
 the Mosaic TPU compiler lowers: lane/sublane rotations
 (``pltpu.roll``), iotas, compares, selects and full reductions.  There is
@@ -87,10 +89,31 @@ def shift_right_flat(cur, prev, n):
     return jnp.where(lane >= n, y, w)
 
 
+def fold_tiles(x, op):
+    """One ``(R, L)`` tile folded elementwise from the tiles of a
+    ``(G, R, L)`` block with ``op`` (a tree of lanewise ops, no cross-lane
+    work); a lone tile is returned as it is."""
+    if x.ndim == 2:
+        return x
+    tiles = [x[g] for g in range(x.shape[0])]
+    while len(tiles) > 1:
+        folded = [op(a, b) for a, b in zip(tiles[::2], tiles[1::2])]
+        tiles = folded + tiles[len(folded) * 2:]
+    return tiles[0]
+
+
+def tile_sums(x):
+    """Per-tile totals of an int32 ``(..., R, L)`` array: a scalar for a
+    lone tile, a tuple of G scalars for a ``(G, R, L)`` block."""
+    if x.ndim == 2:
+        return jnp.sum(x)
+    return tuple(jnp.sum(x[g]) for g in range(x.shape[0]))
+
+
 def all_of(mask):
     """Scalar bool: every element of ``mask`` is set (an int32 min
     reduction; boolean reductions do not lower)."""
-    return jnp.min(mask.astype(jnp.int32)) > 0
+    return jnp.min(fold_tiles(mask.astype(jnp.int32), jnp.minimum)) > 0
 
 
 def prev_tail(xp, k):

@@ -182,11 +182,14 @@ def count_decoded(src: Codec, dst: Codec, a, cp, lead, x, xp, live, gidx, *,
                   validate: bool):
     """Lengths + fused validation over an already-decoded tile.
 
-    Returns the three per-tile scalars ``(total, err_flag,
-    first_err_gidx)`` — first-error offsets are in *global* stream
-    coordinates (callers subtract the document start).
+    Returns the three scalars ``(total, err_flag, first_err_gidx)`` —
+    first-error offsets are in *global* stream coordinates (callers
+    subtract the document start).  Over a ``(G, ROWS, LANES)`` block,
+    ``total`` is a tuple of the G tiles' totals and the two error
+    scalars are folded over the block (their carries fold by max/min
+    anyway).
     """
-    tot = jnp.sum(jnp.where(lead & live, dst.unit_len(cp), 0))
+    tot = common.tile_sums(jnp.where(lead & live, dst.unit_len(cp), 0))
     if validate:
         # Fused validation, one scan: the maximal-subpart map locates the
         # first decode error at its lead (Python exc.start semantics) and
@@ -199,12 +202,20 @@ def count_decoded(src: Codec, dst: Codec, a, cp, lead, x, xp, live, gidx, *,
         err = sub
         if src.extra_err is not None:
             err = err | (src.extra_err(x, xp) & live)
-        err_flag = jnp.max(err.astype(jnp.int32))
-        ferr = jnp.min(jnp.where(sub, gidx, _IMAX))
+        err_flag, ferr = _error_scalars(err, sub, gidx)
     else:
         err_flag = jnp.int32(0)
         ferr = jnp.int32(_IMAX)
     return tot, err_flag, ferr
+
+
+def _error_scalars(err, sub, gidx):
+    """``(err_flag, first_err_gidx)`` over a tile or a block of tiles."""
+    err_flag = jnp.max(common.fold_tiles(err.astype(jnp.int32),
+                                         jnp.maximum))
+    ferr = jnp.min(common.fold_tiles(jnp.where(sub, gidx, _IMAX),
+                                     jnp.minimum))
+    return err_flag, ferr
 
 
 def count_decoded2(src: Codec, dst: Codec, a, cp, lead, live, gidx, *,
@@ -218,11 +229,10 @@ def count_decoded2(src: Codec, dst: Codec, a, cp, lead, live, gidx, *,
     sticky per-document ``(err, ferr)`` folds are unchanged even though
     a per-tile flag may fire in a different tile than KL would have.
     """
-    tot = jnp.sum(jnp.where(lead & live, dst.unit_len(cp), 0))
+    tot = common.tile_sums(jnp.where(lead & live, dst.unit_len(cp), 0))
     if validate:
         sub = _encode_err(dst, a, live)
-        err_flag = jnp.max(sub.astype(jnp.int32))
-        ferr = jnp.min(jnp.where(sub, gidx, _IMAX))
+        err_flag, ferr = _error_scalars(sub, sub, gidx)
     else:
         err_flag = jnp.int32(0)
         ferr = jnp.int32(_IMAX)
@@ -230,7 +240,9 @@ def count_decoded2(src: Codec, dst: Codec, a, cp, lead, live, gidx, *,
 
 
 def _route(arrs, towards_front: bool, nbits: int):
-    """A log-step routing network over ``(R, LANES)`` tiles.
+    """A log-step routing network over ``(R, LANES)`` tiles (or ``(G, R,
+    LANES)`` blocks: every tile routes on its own, through the same
+    stages).
 
     ``arrs[0]`` is the per-slot unit count (0 marks an empty slot) and
     ``arrs[1]`` the displacement each slot travels; the other arrays
@@ -240,7 +252,7 @@ def _route(arrs, towards_front: bool, nbits: int):
     to low — and keeps the others.  The stages run as a loop with a
     dynamic rotation amount, so the network traces once.
     """
-    nelem = arrs[0].size
+    nelem = arrs[0].shape[-2] * arrs[0].shape[-1]
 
     def stage(k, arrs):
         if towards_front:
@@ -281,9 +293,10 @@ def _compact_units(eff, planes, out_rows: int, prefix: bool):
     static flat shift), where ``j < eff``.  Returns the ``(out_rows,
     LANES)`` int32 window holding the ``sum(eff)`` units at its front and
     zeros behind.  Exact by construction: integers are only moved and
-    selected, never combined arithmetically.
+    selected, never combined arithmetically.  A ``(G, R, LANES)`` block
+    compacts each tile on its own, through one shared network.
     """
-    nelem = eff.size
+    nelem = eff.shape[-2] * eff.shape[-1]
     if prefix:
         eff_c, planes_c = eff, planes
     else:
@@ -293,12 +306,13 @@ def _compact_units(eff, planes, out_rows: int, prefix: bool):
         arrs = _route([eff, disp, *planes], True, (nelem - 1).bit_length())
         eff_c, planes_c = arrs[0], list(arrs[2:])
 
-    pad = out_rows - eff.shape[0]
+    pad = out_rows - eff.shape[-2]
 
     def grow(a):
         if not pad:
             return a
-        return jnp.concatenate([a, jnp.zeros((pad, LANES), a.dtype)])
+        zeros = jnp.zeros(a.shape[:-2] + (pad, LANES), a.dtype)
+        return jnp.concatenate([a, zeros], axis=-2)
 
     if len(planes) == 1:
         # One unit per lane: the compacted lanes already sit at their
@@ -321,8 +335,8 @@ def stage_decoded(src: Codec, dst: Codec, cp, lead, instream):
     """In-tile compaction of an already-decoded tile: the staging body.
 
     Returns the compact int32 stage window as ``(stage_width(src, dst) //
-    LANES, LANES)`` rows, units at the front; the caller stores it at
-    the tile's base offset.
+    LANES, LANES)`` rows (per tile of a block), units at the front; the
+    caller stores it at the tile's base offset.
     """
     eff = jnp.where(lead & instream, dst.unit_len(cp), 0)
     nun = stage_units(src, dst)
@@ -400,9 +414,13 @@ def onepass_tile(src: Codec, dst: Codec, x, xp, xn, live, gidx, *,
     Returns ``(total, err_flag, first_err_gidx, stage)`` — the count
     pass's three per-tile scalars plus the write pass's compact stage
     window, computed from ONE decode/analysis of the tile (the fused
-    two-pass pipeline decodes every tile twice).  With ``ascii_skip``
-    the whole body sits behind a nested per-tile ``lax.cond`` — the
-    three-way tile-class dispatch of DESIGN.md §9:
+    two-pass pipeline decodes every tile twice).  ``x`` may also be a
+    ``(G, ROWS, LANES)`` block of G consecutive tiles, with ``xp`` /
+    ``xn`` each tile's own neighbours: ``total`` is then a tuple of the
+    G tiles' totals, ``stage`` holds one window per tile, and the error
+    scalars are folded over the block.  With ``ascii_skip`` the whole
+    body sits behind a nested ``lax.cond`` — the three-way tile-class
+    dispatch of DESIGN.md §9, taken once per block:
 
       ASCII     pure-ASCII tile, pure-ASCII boundary inflow
                 (:func:`ascii_tile_pred` over ``src.max_lookback``
@@ -420,7 +438,9 @@ def onepass_tile(src: Codec, dst: Codec, x, xp, xn, live, gidx, *,
 
     Each class is lanewise bit-identical to the general body wherever
     its predicate admits a tile, so dispatch on/off (``ascii_skip``)
-    never changes (buffer, count, status).
+    never changes (buffer, count, status).  A block takes a class only
+    when every tile of it would (the predicates hold over the whole
+    block), so dispatching per block does not change them either.
     """
     width = stage_width(src, dst)
 
@@ -448,10 +468,11 @@ def onepass_tile(src: Codec, dst: Codec, x, xp, xn, live, gidx, *,
         # ASCII lanes are 1 destination unit in every matrix format and
         # never claim (or get claimed by) a neighbour; dead lanes are
         # zeros, so the tile is already the front of the compact window.
-        tot = jnp.sum(live.astype(jnp.int32))
-        pad = jnp.zeros((width // LANES - x.shape[0], LANES), jnp.int32)
+        tot = common.tile_sums(live.astype(jnp.int32))
+        rows = width // LANES - x.shape[-2]
+        pad = jnp.zeros(x.shape[:-2] + (rows, LANES), jnp.int32)
         return tot, jnp.int32(0), jnp.int32(_IMAX), \
-            jnp.concatenate([x, pad]) if pad.shape[0] else x
+            jnp.concatenate([x, pad], axis=-2) if rows else x
 
     if src.class2_pred is None:
         inner = general

@@ -448,3 +448,211 @@ def test_ragged_strategy_rejects_unknown():
     with pytest.raises(ValueError, match="strategy"):
         tc.ragged_transcode(pk.data, pk.offsets, pk.lengths,
                             strategy="blockparallel")
+
+
+# ---------------------------------------------------------------------------
+# Block geometry: one grid step handles a block of up to ``op.G_MAX``
+# consecutive tiles (DESIGN.md §9).  Every case below runs at inputs of
+# one tile, of fewer tiles than a block, of whole blocks, and of whole
+# blocks plus a partial last one.
+
+G_MAX = op.G_MAX
+GEOMETRIES = {"one-tile": 1, "sub-block": 3,
+              "partial-last-block": 2 * G_MAX + 3, "full-blocks": 3 * G_MAX}
+
+
+def _block_shapes(eqn):
+    """``(grid, [block shape of each 3-D operand])`` of a pallas_call."""
+    gm = eqn.params["grid_mapping"]
+    shapes = [tuple(getattr(d, "block_size", d) for d in bm.block_shape)
+              for bm in gm.block_mappings]
+    return gm.grid, [s for s in shapes if len(s) == 3]
+
+
+def _edges(ntiles):
+    """Tile edges worth a probe: the first inside a block, the first
+    between blocks, and the last one."""
+    g = op.tiles_per_step(ntiles * BLOCK)
+    edges = {BLOCK if g > 1 else None,
+             g * BLOCK if g < ntiles else None,
+             (ntiles - 1) * BLOCK or None}
+    return sorted(e for e in edges if e)
+
+
+def _vs_fused(buf, n, errors, src="utf8", dst="utf16"):
+    a = op.transcode_onepass(jnp.asarray(buf), n, src=src, dst=dst,
+                             errors=errors)
+    f = ft.transcode_fused(jnp.asarray(buf), n, src=src, dst=dst,
+                           errors=errors)
+    _assert_identical(a, f, (len(buf), n, errors, src, dst))
+    return a
+
+
+def test_tiles_per_step_is_the_largest_power_of_two_that_fits():
+    assert op.G_MAX & (op.G_MAX - 1) == 0
+    for n in (0, 1, BLOCK, BLOCK + 1, 3 * BLOCK, 8 * BLOCK - 1,
+              G_MAX * BLOCK, 100 * G_MAX * BLOCK):
+        g = op.tiles_per_step(n)
+        ntiles = max(1, -(-n // BLOCK))
+        assert g & (g - 1) == 0 and g <= min(G_MAX, ntiles), (n, g)
+        assert 2 * g > min(G_MAX, ntiles), (n, g)
+
+
+@pytest.mark.parametrize("geometry", GEOMETRIES)
+def test_onepass_steps_over_blocks_of_tiles(geometry):
+    """The launch's grid walks blocks of ``tiles_per_step`` tiles, with
+    the tiles before and after each block as single-tile operands."""
+    n = GEOMETRIES[geometry] * BLOCK - 7
+    g = op.tiles_per_step(n)
+    jaxpr = jax.make_jaxpr(lambda x: op.transcode_onepass(
+        x, src="utf8", dst="utf16", ascii_fastpath=False))(
+            jnp.zeros((n,), jnp.uint8)).jaxpr
+    (eqn,) = pallas_eqns(jaxpr)
+    grid, blocks = _block_shapes(eqn)
+    assert grid == (-(-GEOMETRIES[geometry] // g),)
+    assert blocks[:3] == [(1, 8, 128), (g, 8, 128), (1, 8, 128)]
+
+
+def test_ragged_kernel_keeps_one_tile_per_step():
+    """The ragged kernel's tiles carry per-document ownership, so it keeps
+    one tile per grid step however many tiles the batch holds."""
+    docs = [np.full(1500, 0x41, np.uint8) for _ in range(G_MAX)]
+    pk = packing.pack_documents(docs, dtype=np.uint8)
+    jaxpr = jax.make_jaxpr(
+        lambda d, o, l: rt.transcode_ragged(d, o, l, src="utf8",
+                                            dst="utf16",
+                                            strategy="onepass")
+    )(jnp.asarray(pk.data), jnp.asarray(pk.offsets),
+      jnp.asarray(pk.lengths)).jaxpr
+    (eqn,) = pallas_eqns(jaxpr)
+    grid, blocks = _block_shapes(eqn)
+    assert grid[0] >= 2 * G_MAX
+    assert blocks[:3] == [(1, 8, 128)] * 3
+
+
+@pytest.mark.parametrize("errors", ["strict", "replace"])
+@pytest.mark.parametrize("geometry", GEOMETRIES)
+def test_onepass_block_boundary_straddling(geometry, errors):
+    """Characters and truncated leads straddling tile edges inside a
+    block and between blocks: the per-tile prefix inside a block and
+    the carry between blocks must put every window where fused does."""
+    ntiles = GEOMETRIES[geometry]
+    probes = [b"\xf0\x9f\x92\xa9", b"\xe4\xb8\xad", b"\xc3\xa9",
+              b"\xf0\x9f\x92", b"\xc3", b"\xed\xa0\x80"]
+    for probe in probes:
+        for back in range(1, len(probe) + 1):
+            buf = np.full(ntiles * BLOCK, 0x41, np.uint8)
+            # One tile has no edge: the probe runs off the stream's end.
+            for e in _edges(ntiles) or [BLOCK]:
+                lo = e - back
+                piece = probe[: len(buf) - lo]
+                buf[lo: lo + len(piece)] = np.frombuffer(piece, np.uint8)
+            _vs_fused(buf, len(buf), errors)
+
+
+@pytest.mark.parametrize("geometry", GEOMETRIES)
+def test_onepass_block_class_transitions(geometry):
+    """Tiles cycling ASCII, ≤2-byte and general content, with 2-byte
+    characters across every tile edge: blocks whose tiles mix classes
+    take the general body, and the result stays bit-identical to fused
+    and to CPython."""
+    ntiles = GEOMETRIES[geometry]
+    two = np.frombuffer("ب".encode("utf-8"), np.uint8)
+    cjk = np.frombuffer("中".encode("utf-8"), np.uint8)
+    buf = np.full(ntiles * BLOCK, 0x61, np.uint8)
+    for t in range(ntiles):
+        lo = t * BLOCK
+        if t % 3 == 1:
+            buf[lo + 2: lo + BLOCK - 2: 2] = two[0]
+            buf[lo + 3: lo + BLOCK - 1: 2] = two[1]
+        elif t % 3 == 2:
+            buf[lo + 10: lo + 13] = cjk
+        if t:
+            buf[lo - 1], buf[lo] = two
+    for errors in ("strict", "replace"):
+        a = _vs_fused(buf, len(buf), errors)
+    want = np.frombuffer(bytes(buf).decode("utf-8").encode("utf-16-le"),
+                         np.uint16)
+    assert int(a.count) == len(want)
+    assert np.array_equal(np.asarray(a.buffer)[: len(want)], want)
+
+
+@pytest.mark.parametrize("geometry", GEOMETRIES)
+def test_onepass_block_ascii_after_multibyte_inflow(geometry):
+    """Pure-ASCII tiles whose previous tile ends in lead or continuation
+    bytes, at an edge inside a block and at one between blocks."""
+    ntiles = GEOMETRIES[geometry]
+    tails = [b"\xc3", b"\xf0\x9f\x92", b"\x80", b"\xc3\xa9"]
+    for k, tail in enumerate(tails):
+        buf = np.full(ntiles * BLOCK, 0x61, np.uint8)
+        for e in _edges(ntiles) or [BLOCK]:
+            buf[e - len(tail): e] = np.frombuffer(tail, np.uint8)
+        for errors in ("strict", "replace"):
+            _vs_fused(buf, len(buf), errors)
+
+
+@pytest.mark.parametrize("geometry", GEOMETRIES)
+def test_onepass_block_n_valid_and_zero(geometry):
+    """``n_valid`` ending inside the last tile, on a tile edge, and
+    inside an earlier block; and ``n_valid=0`` at every geometry."""
+    ntiles = GEOMETRIES[geometry]
+    b = synthetic.utf8_array("chinese", ntiles * BLOCK, seed=11)
+    buf = np.zeros(ntiles * BLOCK, np.uint8)
+    buf[:] = b[: len(buf)] if len(b) >= len(buf) else np.pad(
+        b, (0, len(buf) - len(b)))
+    g = op.tiles_per_step(len(buf))
+    for n in sorted({len(buf) - 5, (ntiles - 1) * BLOCK,
+                     max(1, (ntiles - g) * BLOCK - 3), 0}):
+        r = _vs_fused(buf, n, "strict")
+        if n == 0:
+            assert int(r.count) == 0 and int(r.status) == -1
+
+
+def test_onepass_mixed_class_block_dispatches_general():
+    """A block takes a class only when every tile of it would: ASCII next
+    to ≤2-byte tiles takes the ≤2-byte body, and one wide tile sends the
+    whole block to the general body — bit-identical to fused either
+    way."""
+    from repro.kernels.stages import driver as sdrv
+    utf8 = stages.get_codec("utf8")
+    two = np.frombuffer("ب".encode("utf-8"), np.uint8)
+    buf = np.full(4 * BLOCK, 0x61, np.uint8)
+    buf[BLOCK: 2 * BLOCK: 2], buf[BLOCK + 1: 2 * BLOCK: 2] = two
+    blk = jnp.asarray(buf.reshape(4, 8, 128).astype(np.int32))
+    prev = jnp.concatenate([jnp.zeros((1, 8, 128), jnp.int32), blk[:-1]])
+    assert bool(sdrv.ascii_tile_pred(blk[0], prev[0]))
+    assert not bool(sdrv.ascii_tile_pred(blk, prev))
+    assert bool(utf8.class2_pred(blk, prev))
+    _vs_fused(buf, len(buf), "strict")
+    buf[3 * BLOCK + 9: 3 * BLOCK + 12] = np.frombuffer(
+        "中".encode("utf-8"), np.uint8)
+    blk = jnp.asarray(buf.reshape(4, 8, 128).astype(np.int32))
+    prev = jnp.concatenate([jnp.zeros((1, 8, 128), jnp.int32), blk[:-1]])
+    assert bool(utf8.class2_pred(blk[:3], prev[:3]))
+    assert not bool(utf8.class2_pred(blk, prev))
+    for errors in ("strict", "replace"):
+        _vs_fused(buf, len(buf), errors)
+
+
+@pytest.mark.parametrize("errors", ["strict", "replace"])
+@pytest.mark.parametrize("src,dst", [("utf8", "utf16"), ("utf16", "utf8"),
+                                     ("utf8", "latin1"), ("utf32", "utf16")])
+def test_onepass_block_fuzz_vs_fused(src, dst, errors):
+    """Garbage and class-biased content over whole blocks plus a partial
+    one: onepass (blocks of tiles) against fused (one tile per step)."""
+    rng = np.random.default_rng(20261018)
+    codec = stages.get_codec(src)
+    ntiles = GEOMETRIES["partial-last-block"]
+    hi = _GEN_HI[codec.itemsize]
+    for trial in range(2):
+        arr = rng.integers(0, 0xE0 if codec.itemsize == 1 else 0x800,
+                           ntiles * BLOCK)
+        for t in rng.choice(ntiles, ntiles // 2, replace=False):
+            kind = rng.integers(3)
+            lo, hi_t = t * BLOCK, (t + 1) * BLOCK
+            arr[lo:hi_t] = (rng.integers(0, hi, BLOCK) if kind == 0 else
+                            rng.integers(0x20, 0x7F, BLOCK) if kind == 1
+                            else arr[lo:hi_t])
+        arr = arr.astype(codec.dtype)
+        n = int(rng.integers((ntiles - 2) * BLOCK, ntiles * BLOCK))
+        _vs_fused(arr, n, errors, src, dst)

@@ -1,11 +1,12 @@
 """The main-path kernels compile for a TPU v5e chip.
 
 Each test lowers one kernel wrapper with ``interpret=False`` (Mosaic) at
-real widths -- a ``1 << 17``-character document, or the 64-document
-packed batch -- and compiles it for a described, not attached, ``v5e``
-chip.  What the chip's compiler refuses (unlowerable primitives, block
-shapes, more SMEM than the chip has) fails here without a chip.  The
-compiled HLO must hold the Mosaic kernel (``tpu_custom_call``).
+real widths -- a ``1 << 17``-character document, the bulk benchmark
+cell's 4 MiB call, or the 64-document packed batch -- and compiles it
+for a described, not attached, ``v5e`` chip.  What the chip's compiler
+refuses (unlowerable primitives, block shapes, more SMEM than the chip
+has) fails here without a chip.  The compiled HLO must hold the Mosaic
+kernel (``tpu_custom_call``).
 
 The topology is described inside a fixture, never while a module is
 imported: only one process at a time may load the TPU compiler library,
@@ -75,6 +76,20 @@ def test_onepass_compiles_for_v5e(one_chip, src, dst, errors):
         lambda x: op.transcode_onepass(x, src=src, dst=dst, errors=errors,
                                        interpret=False),
         _doc(src, one_chip))
+    assert "tpu_custom_call" in text
+
+
+def test_onepass_bulk_cell_shape_compiles_for_v5e(one_chip):
+    """The bulk benchmark cell's call: 4 MiB of UTF-8 to UTF-16, strict,
+    validated, which runs blocks of ``G_MAX`` tiles per grid step: the
+    block's vregs, VMEM buffers and SMEM carry fit the chip."""
+    n = 4 << 20
+    assert op.tiles_per_step(n) == op.G_MAX
+    text = _compile_text(
+        lambda x: op.transcode_onepass(x, src="utf8", dst="utf16",
+                                       validate=True, errors="strict",
+                                       interpret=False),
+        jax.ShapeDtypeStruct((n,), jnp.uint8, sharding=one_chip))
     assert "tpu_custom_call" in text
 
 
