@@ -93,6 +93,21 @@ def test_onepass_bulk_cell_shape_compiles_for_v5e(one_chip):
     assert "tpu_custom_call" in text
 
 
+def test_onepass_utf16_bulk_cell_shape_compiles_for_v5e(one_chip):
+    """The UTF-16 bulk benchmark cell's call: 2^21 units of UTF-16 to
+    UTF-8, strict, validated, in blocks of ``G_MAX`` tiles: the uint8
+    windows of three bytes per unit, with their block buffer, fit the
+    chip."""
+    n = 2 << 20
+    assert op.tiles_per_step(n) == op.G_MAX
+    text = _compile_text(
+        lambda x: op.transcode_onepass(x, src="utf16", dst="utf8",
+                                       validate=True, errors="strict",
+                                       interpret=False),
+        jax.ShapeDtypeStruct((n,), jnp.uint16, sharding=one_chip))
+    assert "tpu_custom_call" in text
+
+
 @pytest.mark.parametrize("src,dst", PAIRS)
 def test_scan_compiles_for_v5e(one_chip, src, dst):
     text = _compile_text(
