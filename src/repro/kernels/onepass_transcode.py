@@ -35,8 +35,11 @@ allows), and:
 A grid step costs a fixed amount on the chip besides its vector work
 (pipelining three input blocks, the branch dispatch, one DMA and its
 wait); a block pays it once for G tiles, and the G tiles' vector work
-forms G independent dependency chains.  A G=1 block is the one-tile
-step the ragged kernel still runs.
+forms G independent dependency chains.  The ragged kernel
+(``repro.kernels.ragged_transcode``) steps over blocks the same way,
+with :func:`tiles_per_step`, :func:`_block_specs` and
+:func:`_load_block`, and each tile of its blocks keeps its own
+document.
 
 The whole-buffer ASCII ``lax.cond`` of the two-pass wrappers additionally
 becomes a **per-block three-way class dispatch** (paper Algorithm 3 at
@@ -105,13 +108,22 @@ KERNEL_NAME = "onepass_transcode"
 # -> UTF-16 calls of the general and the ≤2-byte tile class (PERF.md).
 G_MAX = 32
 
+# The same for the ragged kernel, whose blocks span documents, so that
+# nearly every block of its packed batches takes the general body.  On
+# the chip, per 1,024-tile batch of packed UTF-8 documents to UTF-16
+# with errors="replace": 3.265 ms at G=1, 0.828 at 8, 0.757 at 16, 0.743
+# at 32, compiling in 2.0, 3.5, 5.6 and 10.3 s.  G=16 is within 3% of
+# the best, but its compile raised the batch program's set-up by 16%;
+# G=8 gives up 9% of kernel time, about 1% of a batch (PERF.md).
+RAGGED_G_MAX = 8
 
-def tiles_per_step(n: int) -> int:
+
+def tiles_per_step(n: int, cap: int = G_MAX) -> int:
     """Tiles per grid step for an ``n``-element input: the largest power
-    of two up to ``G_MAX`` that does not exceed its tile count, so a
+    of two up to ``cap`` that does not exceed its tile count, so a
     short input is never padded to a whole block."""
     nblk = max(1, -(-n // BLOCK))
-    return min(G_MAX, 1 << (nblk.bit_length() - 1))
+    return min(cap, 1 << (nblk.bit_length() - 1))
 
 
 def _block_specs(g: int):

@@ -57,10 +57,20 @@ ownership masks (cross-document inflow zeroing + per-tile live ends) are
 exactly the "per-tile ownership resets" the carry needs.  Per-tile
 ``(total, err, ferr)`` scalars still leave the kernel — they are the
 *product* (the per-document segment reductions consume them), not
-inter-pass coordination.  The per-tile three-way class dispatch (ASCII
-copy / narrowed ≤2-byte body / general, DESIGN.md §9) rides along, so an
-ASCII document packed next to a CJK document keeps its copy path and a
-dense 2-byte document keeps the narrowed class, tile by tile.
+inter-pass coordination.
+
+Like the single-stream one-pass kernel, each grid step handles a block
+of G consecutive tiles, ``(G, ROWS, LANES)``, G from the batch's tile
+count (``onepass_transcode.tiles_per_step`` up to ``RAGGED_G_MAX``).
+A block usually spans several documents, so ownership stays per tile
+inside it: tile j's neighbour inflow is zeroed by its own
+``same_prev`` / ``same_next``, its live mask ends at its own document's
+end, and its ``(total, err, ferr)`` come out on their own instead of
+folded over the block.  The three-way class dispatch (ASCII copy /
+narrowed ≤2-byte body / general, DESIGN.md §9) is taken once per block:
+a block takes a class only when each of its tiles would, so an ASCII
+document that shares a block with a CJK one runs the general body
+there, with the same result.
 """
 
 from __future__ import annotations
@@ -78,6 +88,7 @@ from repro import spans
 from repro.core import compaction, packing
 from repro.core import result as R
 from repro.kernels import fused_transcode as ft
+from repro.kernels import onepass_transcode as op
 from repro.kernels import runtime
 from repro.kernels import stages
 from repro.kernels.stages import driver as sdrv
@@ -144,16 +155,26 @@ def _doc_reduce(totals, errs, ferrs, tile_doc, offsets, validate):
 
 
 def _owned_tiles(end_ref, sp_ref, sn_ref, xp_ref, x_ref, xn_ref):
-    """The tile and its neighbours under the ownership map: inflow from a
-    neighbour tile of a DIFFERENT document reads as zeros, exactly like
-    the zero boundary tiles of the single-stream pipeline.  Returns
-    ``(x, xp, xn, live, gidx)``."""
+    """The tile (or the ``(G, ROWS, LANES)`` block of tiles) and each
+    tile's neighbours under the ownership map: inflow from a neighbour
+    tile of a DIFFERENT document reads as zeros, exactly like the zero
+    boundary tiles of the single-stream pipeline, and each tile's live
+    mask ends at its own document's end.  Returns ``(x, xp, xn, live,
+    gidx)``."""
     i = pl.program_id(0)
-    x = ft._load(x_ref)
-    xp = ft._load(xp_ref) * sp_ref[i]
-    xn = ft._load(xn_ref) * sn_ref[i]
+    x, xp, xn = op._load_block(xp_ref, x_ref, xn_ref)
     gidx = ft._gidx(x.shape)
-    return x, xp, xn, gidx < end_ref[i], gidx
+    if x.ndim == 2:
+        return x, xp * sp_ref[i], xn * sn_ref[i], gidx < end_ref[i], gidx
+    g = x.shape[0]
+
+    def per_tile(ref):
+        # Tile j's scalar of the block, broadcast over its lanes.
+        return jnp.stack([jnp.full(x.shape[1:], ref[i * g + j], jnp.int32)
+                          for j in range(g)])
+
+    return (x, xp * per_tile(sp_ref), xn * per_tile(sn_ref),
+            gidx < per_tile(end_ref), gidx)
 
 
 def _rcount_kernel(end_ref, sp_ref, sn_ref, xp_ref, x_ref, xn_ref,
@@ -180,19 +201,29 @@ def _rwrite_kernel(end_ref, sp_ref, sn_ref, base_ref, tot_ref,
     ft.store_window(stage, base_ref[i], tot_ref[i], out_ref, buf, pend, sem)
 
 
-def _launch_geometry(data, offsets, lengths):
+def _launch_geometry(data, offsets, lengths, g=1):
     """ONE definition of the ragged launch setup, shared by every ragged
     kernel call (count/write/onepass): the ownership map, the masked +
     boundary-tiled data, and the matching in_specs/operand prefix.
     Desynchronizing these between the bodies would compute base offsets
     on a different tiling than the writer stores with.
+
+    With ``g > 1`` the data is tiled in blocks of ``g`` tiles (as
+    ``op._block_specs(g)`` reads it) and the ownership vectors are
+    padded to whole blocks with dead tiles (``tile_end`` 0, no
+    neighbours).  Returns ``(nblk, tile_doc, owned, d3)``, ``nblk`` the
+    data's own tiles.
     """
     nblk = _nblk(data.shape[0])
     tile_doc, tile_end, same_prev, same_next = packing.tile_ownership(
         offsets, lengths, nblk, BLOCK)
     dm = _mask_to_docs(data, tile_end, nblk)
-    d3, _ = runtime.tile_with_boundaries(dm, ROWS, LANES, boundary_tiles=2)
-    return nblk, tile_doc, (tile_end, same_prev, same_next), d3
+    d3, padded = runtime.tile_with_boundaries(dm, ROWS, LANES,
+                                              boundary_tiles=2, block=g)
+    owned = (tile_end, same_prev, same_next)
+    if padded > nblk:
+        owned = tuple(jnp.pad(v, (0, padded - nblk)) for v in owned)
+    return nblk, tile_doc, owned, d3
 
 
 _OWNED_SPECS = [_SMEM, _SMEM, _SMEM]
@@ -229,29 +260,36 @@ def _ronepass_kernel(end_ref, sp_ref, sn_ref, xp_ref, x_ref, xn_ref,
                      carry, *, src, dst, errors, validate, ascii_skip):
     codec_s, codec_d = stages.get_codec(src), stages.get_codec(dst)
     i = pl.program_id(0)
+    g = x_ref.shape[0]
 
     @pl.when(i == 0)
     def _init():
         carry[0] = 0
 
-    # Ownership masking, exactly as the two-launch kernels: cross-
-    # document neighbour inflow reads as zeros.  (The zeroed inflow also
-    # lets the per-tile ASCII predicate pass at document starts — a
-    # document boundary is a clean inflow by construction.)
+    # Ownership masking, exactly as the two-launch kernels, tile by tile
+    # inside the block: cross-document neighbour inflow reads as zeros.
+    # (The zeroed inflow also lets the ASCII predicate pass at document
+    # starts — a document boundary is a clean inflow by construction.)
     x, xp, xn, live, gidx = _owned_tiles(end_ref, sp_ref, sn_ref, xp_ref,
                                          x_ref, xn_ref)
     tot, err, ferr, stage = sdrv.onepass_tile(
         codec_s, codec_d, x, xp, xn, live, gidx, errors=errors,
-        validate=validate, ascii_skip=ascii_skip)
+        validate=validate, ascii_skip=ascii_skip, per_tile=True)
 
     # Documents are packed in order, so the global running offset IS the
     # per-document segment scan (dense output, no inter-doc padding).
     base = carry[0]
     ft.store_window(stage, base, tot, out_ref, buf, pend, sem)
-    carry[0] = base + tot
+    if g == 1:
+        tot, err, ferr = (tot,), (err,), (ferr,)
+    carry[0] = base + sum(tot)
     # Per-tile scalars are the per-document reduction's INPUT (segment
-    # sum/min/max downstream), not inter-pass coordination.
-    tot_ref[i], err_ref[i], ferr_ref[i] = tot, err, ferr
+    # sum/min/max downstream), not inter-pass coordination; each tile of
+    # the block keeps its own, since its neighbours may belong to other
+    # documents.
+    for j in range(g):
+        tot_ref[i * g + j], err_ref[i * g + j], ferr_ref[i * g + j] = \
+            tot[j], err[j], ferr[j]
 
 
 @functools.partial(jax.jit, static_argnames=("src", "dst", "validate",
@@ -260,18 +298,21 @@ def _ragged_onepass_impl(data, offsets, lengths, src, dst, validate,
                          interpret, errors):
     codec_s, codec_d, factor = stages.get_pair(src, dst)
     width = stages.stage_width(codec_s, codec_d)
-    nblk, tile_doc, owned, d3 = _launch_geometry(data, offsets, lengths)
+    g = op.tiles_per_step(data.shape[0], op.RAGGED_G_MAX)
+    nblk, tile_doc, owned, d3 = _launch_geometry(data, offsets, lengths, g)
     ft.check_launch(nblk, factor, 6, interpret, "ragged transcode")
     kernel = functools.partial(_ronepass_kernel, src=src, dst=dst,
                                errors=errors, validate=validate,
                                ascii_skip=True)
-    per_tile = jax.ShapeDtypeStruct((nblk,), jnp.int32)
-    out_spec, out_shape, scratch = ft.window_out(nblk, width, codec_d.dtype)
+    padded = owned[0].shape[0]
+    per_tile = jax.ShapeDtypeStruct((padded,), jnp.int32)
+    out_spec, out_shape, scratch = ft.window_out(padded, width,
+                                                 codec_d.dtype, g)
     with jax.named_scope(ONEPASS_KERNEL_NAME):
         outp, totals, errs, ferrs = pl.pallas_call(
             kernel,
-            grid=(nblk,),
-            in_specs=_OWNED_SPECS + _TILE_SPECS,
+            grid=(padded // g,),
+            in_specs=_OWNED_SPECS + op._block_specs(g),
             out_specs=[out_spec, _SMEM, _SMEM, _SMEM],
             out_shape=[out_shape, per_tile, per_tile, per_tile],
             scratch_shapes=scratch + [pltpu.SMEM((1,), jnp.int32)],
@@ -280,6 +321,8 @@ def _ragged_onepass_impl(data, offsets, lengths, src, dst, validate,
             name=ONEPASS_KERNEL_NAME,
         )(*owned, d3, d3, d3)
     outp = outp.reshape(-1)
+    # The block padding's dead tiles hold nothing.
+    totals, errs, ferrs = totals[:nblk], errs[:nblk], ferrs[:nblk]
     total = jnp.sum(totals)
     cap = factor * nblk * BLOCK
     outp = outp[:cap]

@@ -102,12 +102,26 @@ def fold_tiles(x, op):
     return tiles[0]
 
 
+def _per_tile(x, reduce):
+    if x.ndim == 2:
+        return reduce(x)
+    return tuple(reduce(x[g]) for g in range(x.shape[0]))
+
+
 def tile_sums(x):
     """Per-tile totals of an int32 ``(..., R, L)`` array: a scalar for a
     lone tile, a tuple of G scalars for a ``(G, R, L)`` block."""
-    if x.ndim == 2:
-        return jnp.sum(x)
-    return tuple(jnp.sum(x[g]) for g in range(x.shape[0]))
+    return _per_tile(x, jnp.sum)
+
+
+def tile_max(x):
+    """Per-tile maxima, shaped as :func:`tile_sums`'s totals."""
+    return _per_tile(x, jnp.max)
+
+
+def tile_min(x):
+    """Per-tile minima, shaped as :func:`tile_sums`'s totals."""
+    return _per_tile(x, jnp.min)
 
 
 def all_of(mask):

@@ -179,15 +179,17 @@ def decode_once2(src: Codec, x, xp, xn, *, errors: str, validate: bool):
 
 
 def count_decoded(src: Codec, dst: Codec, a, cp, lead, x, xp, live, gidx, *,
-                  validate: bool):
+                  validate: bool, per_tile: bool = False):
     """Lengths + fused validation over an already-decoded tile.
 
     Returns the three scalars ``(total, err_flag, first_err_gidx)`` —
     first-error offsets are in *global* stream coordinates (callers
     subtract the document start).  Over a ``(G, ROWS, LANES)`` block,
     ``total`` is a tuple of the G tiles' totals and the two error
-    scalars are folded over the block (their carries fold by max/min
-    anyway).
+    scalars are folded over the block (one stream's carries fold by
+    max/min anyway) — or, with ``per_tile``, are tuples of each tile's
+    own, for a caller whose tiles belong to different documents and
+    reduce per document downstream.
     """
     tot = common.tile_sums(jnp.where(lead & live, dst.unit_len(cp), 0))
     if validate:
@@ -202,15 +204,18 @@ def count_decoded(src: Codec, dst: Codec, a, cp, lead, x, xp, live, gidx, *,
         err = sub
         if src.extra_err is not None:
             err = err | (src.extra_err(x, xp) & live)
-        err_flag, ferr = _error_scalars(err, sub, gidx)
+        err_flag, ferr = _error_scalars(err, sub, gidx, per_tile)
     else:
-        err_flag = jnp.int32(0)
-        ferr = jnp.int32(_IMAX)
+        err_flag, ferr = _no_errors(x, per_tile)
     return tot, err_flag, ferr
 
 
-def _error_scalars(err, sub, gidx):
-    """``(err_flag, first_err_gidx)`` over a tile or a block of tiles."""
+def _error_scalars(err, sub, gidx, per_tile):
+    """``(err_flag, first_err_gidx)`` over a tile or a block of tiles,
+    or with ``per_tile`` of each tile of the block."""
+    if per_tile:
+        return (common.tile_max(err.astype(jnp.int32)),
+                common.tile_min(jnp.where(sub, gidx, _IMAX)))
     err_flag = jnp.max(common.fold_tiles(err.astype(jnp.int32),
                                          jnp.maximum))
     ferr = jnp.min(common.fold_tiles(jnp.where(sub, gidx, _IMAX),
@@ -218,8 +223,16 @@ def _error_scalars(err, sub, gidx):
     return err_flag, ferr
 
 
+def _no_errors(x, per_tile):
+    """The error scalars of clean tiles, shaped as :func:`_error_scalars`
+    shapes them for ``x``."""
+    if per_tile and x.ndim == 3:
+        return (jnp.int32(0),) * x.shape[0], (jnp.int32(_IMAX),) * x.shape[0]
+    return jnp.int32(0), jnp.int32(_IMAX)
+
+
 def count_decoded2(src: Codec, dst: Codec, a, cp, lead, live, gidx, *,
-                   validate: bool):
+                   validate: bool, per_tile: bool = False):
     """Class-specialized :func:`count_decoded` for a ≤2-byte tile.
 
     The extra Keiser-Lemire detector is skipped (its three nibble-table
@@ -232,10 +245,9 @@ def count_decoded2(src: Codec, dst: Codec, a, cp, lead, live, gidx, *,
     tot = common.tile_sums(jnp.where(lead & live, dst.unit_len(cp), 0))
     if validate:
         sub = _encode_err(dst, a, live)
-        err_flag, ferr = _error_scalars(sub, sub, gidx)
+        err_flag, ferr = _error_scalars(sub, sub, gidx, per_tile)
     else:
-        err_flag = jnp.int32(0)
-        ferr = jnp.int32(_IMAX)
+        err_flag, ferr = _no_errors(live, per_tile)
     return tot, err_flag, ferr
 
 
@@ -408,7 +420,8 @@ def ascii_tile_pred(x, xp, lookback: int = 3):
 
 
 def onepass_tile(src: Codec, dst: Codec, x, xp, xn, live, gidx, *,
-                 errors: str, validate: bool, ascii_skip: bool = True):
+                 errors: str, validate: bool, ascii_skip: bool = True,
+                 per_tile: bool = False):
     """Count + stage one tile off a single decode: the one-pass body.
 
     Returns ``(total, err_flag, first_err_gidx, stage)`` — the count
@@ -418,9 +431,10 @@ def onepass_tile(src: Codec, dst: Codec, x, xp, xn, live, gidx, *,
     ``(G, ROWS, LANES)`` block of G consecutive tiles, with ``xp`` /
     ``xn`` each tile's own neighbours: ``total`` is then a tuple of the
     G tiles' totals, ``stage`` holds one window per tile, and the error
-    scalars are folded over the block.  With ``ascii_skip`` the whole
-    body sits behind a nested ``lax.cond`` — the three-way tile-class
-    dispatch of DESIGN.md §9, taken once per block:
+    scalars are folded over the block, or with ``per_tile`` are tuples
+    of each tile's own (:func:`count_decoded`).  With ``ascii_skip`` the
+    whole body sits behind a nested ``lax.cond`` — the three-way
+    tile-class dispatch of DESIGN.md §9, taken once per block:
 
       ASCII     pure-ASCII tile, pure-ASCII boundary inflow
                 (:func:`ascii_tile_pred` over ``src.max_lookback``
@@ -449,7 +463,8 @@ def onepass_tile(src: Codec, dst: Codec, x, xp, xn, live, gidx, *,
         a, cp, lead = decode_once(src, x, xp, xn, errors=errors,
                                   validate=validate)
         tot, err, ferr = count_decoded(src, dst, a, cp, lead, x, xp, live,
-                                       gidx, validate=validate)
+                                       gidx, validate=validate,
+                                       per_tile=per_tile)
         return tot, err, ferr, stage_decoded(src, dst, cp, lead, live)
 
     if not ascii_skip:
@@ -460,7 +475,8 @@ def onepass_tile(src: Codec, dst: Codec, x, xp, xn, live, gidx, *,
         a, cp, lead = decode_once2(src, x, xp, xn, errors=errors,
                                    validate=validate)
         tot, err, ferr = count_decoded2(src, dst, a, cp, lead, live, gidx,
-                                        validate=validate)
+                                        validate=validate,
+                                        per_tile=per_tile)
         return tot, err, ferr, stage_decoded2(src, dst, cp, lead, live)
 
     def ascii(ops):
@@ -471,7 +487,7 @@ def onepass_tile(src: Codec, dst: Codec, x, xp, xn, live, gidx, *,
         tot = common.tile_sums(live.astype(jnp.int32))
         rows = width // LANES - x.shape[-2]
         pad = jnp.zeros(x.shape[:-2] + (rows, LANES), jnp.int32)
-        return tot, jnp.int32(0), jnp.int32(_IMAX), \
+        return tot, *_no_errors(x, per_tile), \
             jnp.concatenate([x, pad], axis=-2) if rows else x
 
     if src.class2_pred is None:

@@ -513,11 +513,17 @@ def test_onepass_steps_over_blocks_of_tiles(geometry):
     assert blocks[:3] == [(1, 8, 128), (g, 8, 128), (1, 8, 128)]
 
 
-def test_ragged_kernel_keeps_one_tile_per_step():
-    """The ragged kernel's tiles carry per-document ownership, so it keeps
-    one tile per grid step however many tiles the batch holds."""
-    docs = [np.full(1500, 0x41, np.uint8) for _ in range(G_MAX)]
+@pytest.mark.parametrize("geometry", GEOMETRIES)
+def test_ragged_steps_over_blocks_of_tiles(geometry):
+    """The ragged kernel's grid walks blocks of ``tiles_per_step`` tiles
+    up to ``RAGGED_G_MAX`` as the onepass kernel's does, whatever
+    documents the tiles belong to."""
+    ntiles = GEOMETRIES[geometry]
+    docs = [np.full(1500, 0x41, np.uint8)] * (ntiles // 2) \
+        + [np.full(7, 0x41, np.uint8)] * (ntiles % 2)
     pk = packing.pack_documents(docs, dtype=np.uint8)
+    assert pk.data.shape[0] == ntiles * BLOCK
+    g = op.tiles_per_step(ntiles * BLOCK, op.RAGGED_G_MAX)
     jaxpr = jax.make_jaxpr(
         lambda d, o, l: rt.transcode_ragged(d, o, l, src="utf8",
                                             dst="utf16",
@@ -526,8 +532,8 @@ def test_ragged_kernel_keeps_one_tile_per_step():
       jnp.asarray(pk.lengths)).jaxpr
     (eqn,) = pallas_eqns(jaxpr)
     grid, blocks = _block_shapes(eqn)
-    assert grid[0] >= 2 * G_MAX
-    assert blocks[:3] == [(1, 8, 128)] * 3
+    assert grid == (-(-ntiles // g),)
+    assert blocks[:3] == [(1, 8, 128), (g, 8, 128), (1, 8, 128)]
 
 
 @pytest.mark.parametrize("errors", ["strict", "replace"])

@@ -11,6 +11,8 @@ from repro.core import packing
 from repro.core import transcode as tc
 from repro.data import pipeline, synthetic
 from repro.kernels import fused_transcode as ft
+from repro.kernels import onepass_transcode as op
+from repro.kernels import ragged_transcode as rt
 
 TILE = packing.TILE
 
@@ -239,6 +241,156 @@ def test_ragged_single_launch_per_pass_jaxpr():
     jaxpr_scan = jax.make_jaxpr(
         lambda d, o, l: tc.ragged_scan_utf8(d, o, l))(*args).jaxpr
     assert len(pallas_eqns(jaxpr_scan)) == 1  # count pass only
+
+
+# ---------------------------------------------------------------------------
+# Blocks of tiles: the one-pass kernel steps over blocks of G tiles, and a
+# block holds tiles of several documents.  Every batch here has one shape
+# (NT tiles, NDOCS document rows), so the cases share their programs.
+
+NT, NDOCS = 12, 12
+_KINDS = {"ascii": (0x20, 0x7E), "two": (0x0621, 0x064A),
+          "cjk": (0x4E00, 0x9FA5), "emoji": (0x1F300, 0x1F6FF)}
+_MALFORMED = [b"\x80", b"\xff", b"\xc3", b"\xe4\xb8", b"\xf0\x9f\x92",
+              b"\xed\xa0\x80", b"\xc0\xaf", b"\xf8\x88\x80\x80\x80"]
+
+
+def _edge():
+    """Tiles per block of an NT-tile batch: the first block edge."""
+    g = op.tiles_per_step(NT * TILE, op.RAGGED_G_MAX)
+    assert 1 < g < NT
+    return g
+
+
+def _text(kind, nbytes, seed=0):
+    """Exactly ``nbytes`` of valid UTF-8: whole characters of one kind,
+    topped up with ASCII."""
+    lo, hi = _KINDS[kind]
+    k = nbytes // len(chr(lo).encode())
+    cps = np.random.default_rng(seed).integers(lo, hi + 1, k)
+    raw = "".join(map(chr, cps)).encode()
+    return raw + b"a" * (nbytes - len(raw))
+
+
+def _splice(raw, at, piece):
+    return raw[:at] + piece + raw[at + len(piece):]
+
+
+def _check_block_batch(docs, errors):
+    """One NT-tile batch: the one-pass kernel bit-exact against
+    strategy="fused", and every document against CPython."""
+    pk = packing.pack_documents(docs, dtype=np.uint8, pad_to_docs=NDOCS)
+    assert pk.data.shape[0] <= NT * TILE
+    data = np.zeros(NT * TILE, np.uint8)
+    data[:pk.data.shape[0]] = pk.data
+    one, ref = (rt.transcode_ragged(data, pk.offsets, pk.lengths,
+                                    src="utf8", dst="utf16", errors=errors,
+                                    strategy=s)
+                for s in ("onepass", "fused"))
+    for field, a, b in zip(one._fields, one, ref):
+        assert np.array_equal(np.asarray(a), np.asarray(b)), field
+    buf = np.asarray(one.buffer)
+    for d, raw in enumerate(docs):
+        try:
+            want, pos = raw.decode("utf-8").encode("utf-16-le"), -1
+        except UnicodeDecodeError as e:
+            want, pos = None, e.start
+        if errors == "replace":
+            want = raw.decode("utf-8", "replace").encode("utf-16-le")
+        assert int(one.statuses[d]) == pos, d
+        if want is not None:
+            want = np.frombuffer(want, np.uint16)
+            lo = int(one.offsets[d])
+            assert int(one.counts[d]) == len(want), d
+            assert np.array_equal(buf[lo: lo + len(want)], want), d
+    pad = slice(len(docs), NDOCS)
+    assert not np.asarray(one.counts)[pad].any()
+    assert (np.asarray(one.statuses)[pad] == -1).all()
+
+
+def _doc_ends_on_block_edge(e):
+    # Doc 0 fills block 0 and ends on its last byte with a truncated
+    # lead; doc 1 starts block 1 with the continuation bytes that would
+    # complete it, were inflow to cross documents.
+    return [_text("two", e * TILE - 1, 1) + b"\xe4",
+            b"\xb8\xad" + _text("cjk", 600, 2),
+            _text("cjk", 1500, 3)]
+
+
+def _character_across_block_edge(e):
+    # Doc 1 runs from tile 1 across the block edge: a 4-byte character
+    # straddles the block edge, a 3-byte one a tile edge inside block 0,
+    # and a truncated 4-byte lead a tile edge inside block 1.
+    doc = _text("ascii", e * TILE + 500, 4)
+    doc = _splice(doc, (e - 1) * TILE - 2, "\U0001F600".encode())
+    doc = _splice(doc, 2 * TILE - 1, "中".encode())
+    doc = _splice(doc, e * TILE - 1, b"\xf0\x9f\x92")
+    return [_text("ascii", 100, 5), doc]
+
+
+def _errors_in_documents_sharing_a_block(e):
+    # Block 0: doc 0's error late in its tile, doc 1's early in the next,
+    # then a valid doc.  Doc 3 is valid and runs into block 1, where doc
+    # 4's error is the first error of the block.
+    return [_splice(_text("ascii", 1000, 6), 900, b"\xff"),
+            _splice(_text("two", 800, 7), 5, b"\xc3"),
+            _text("two", 700, 8),
+            _text("cjk", (e - 4) * TILE + 10, 9),
+            b"ab\xed\xa0\x80" + _text("ascii", 300, 10)]
+
+
+def _empty_documents_in_a_block(e):
+    return [b"", _text("ascii", 300, 11), b"", b"", _text("cjk", 1500, 12),
+            b"", _text("two", (e - 3) * TILE, 13), b"",
+            _text("emoji", 200, 14), b""]
+
+
+def _ascii_and_cjk_in_one_block(e):
+    # Block 0 mixes ASCII documents with a CJK one (the general body);
+    # block 1 holds ASCII only, after a document ending in a 3-byte
+    # character.
+    return [_text("ascii", 2 * TILE, 15), _text("cjk", TILE - 3, 16)
+            + "中".encode(), _text("ascii", TILE - 1, 17),
+            _text("cjk", (e - 4) * TILE, 18),
+            _text("ascii", 3 * TILE + 17, 19)]
+
+
+BLOCK_CASES = {
+    "doc-ends-on-block-edge": _doc_ends_on_block_edge,
+    "character-across-block-edge": _character_across_block_edge,
+    "errors-in-documents-sharing-a-block":
+        _errors_in_documents_sharing_a_block,
+    "empty-documents-in-a-block": _empty_documents_in_a_block,
+    "ascii-and-cjk-in-one-block": _ascii_and_cjk_in_one_block,
+}
+
+
+@pytest.mark.parametrize("errors", ["strict", "replace"])
+@pytest.mark.parametrize("case", BLOCK_CASES)
+def test_ragged_block_edges(case, errors):
+    _check_block_batch(BLOCK_CASES[case](_edge()), errors)
+
+
+@pytest.mark.parametrize("errors", ["strict", "replace"])
+def test_ragged_blocks_fuzz(errors):
+    """Seeded batches of documents of every kind and length, a malformed
+    sequence in about half of them, packed up to NT tiles."""
+    rng = np.random.default_rng(1616)
+    for _ in range(3):
+        docs, tiles = [], 0
+        while len(docs) < NDOCS:
+            n = int(rng.integers(0, 2500))
+            raw = _text(str(rng.choice(list(_KINDS))), n,
+                        int(rng.integers(1 << 30)))
+            if raw and rng.random() < 0.5:
+                at = int(rng.integers(0, len(raw)))
+                raw = raw[:at] + _MALFORMED[rng.integers(len(_MALFORMED))] \
+                    + raw[at:]
+            tiles += -(-len(raw) // TILE)
+            if tiles > NT:
+                break
+            docs.append(raw)
+        _check_block_batch(docs, errors)
 
 
 # ---------------------------------------------------------------------------

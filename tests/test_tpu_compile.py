@@ -2,7 +2,8 @@
 
 Each test lowers one kernel wrapper with ``interpret=False`` (Mosaic) at
 real widths -- a ``1 << 17``-character document, the bulk benchmark
-cell's 4 MiB call, or the 64-document packed batch -- and compiles it
+cell's 4 MiB call, the 64-document packed batch, or the ingest cell's
+1,024-tile batch -- and compiles it
 for a described, not attached, ``v5e`` chip.  What the chip's compiler
 refuses (unlowerable primitives, block shapes, more SMEM than the chip
 has) fails here without a chip.  The compiled HLO must hold the Mosaic
@@ -123,6 +124,25 @@ def test_ragged_onepass_compiles_for_v5e(one_chip, src, dst, errors):
         lambda d, o, n: rt.transcode_ragged(d, o, n, src=src, dst=dst,
                                             errors=errors, interpret=False),
         *_batch(src, one_chip))
+    assert "tpu_custom_call" in text
+
+
+def test_ragged_ingest_cell_shape_compiles_for_v5e(one_chip):
+    """The ingest benchmark cell's batch: 1,024 tiles of UTF-8 padded to
+    one document per tile, to UTF-16 with ``errors="replace"``, which
+    runs blocks of ``RAGGED_G_MAX`` tiles per grid step, each tile with
+    its own document: the block's vregs, VMEM buffers and six per-tile
+    SMEM vectors fit the chip."""
+    n, docs = 1 << 20, 1024
+    assert op.tiles_per_step(n, op.RAGGED_G_MAX) == op.RAGGED_G_MAX
+    text = _compile_text(
+        lambda d, o, l: rt.transcode_ragged(d, o, l, src="utf8",
+                                            dst="utf16", validate=True,
+                                            errors="replace",
+                                            interpret=False),
+        jax.ShapeDtypeStruct((n,), jnp.uint8, sharding=one_chip),
+        jax.ShapeDtypeStruct((docs + 1,), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((docs,), jnp.int32, sharding=one_chip))
     assert "tpu_custom_call" in text
 
 
