@@ -44,6 +44,12 @@ import numpy as np
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 
+# The nine lipsum profiles of the paper's Table 4a (Lemire & Muła,
+# arXiv:2109.10433; ``repro.data.synthetic``), one document of N_CHARS
+# characters each.
+LIPSUM_LANGS = ["arabic", "chinese", "emoji", "hebrew", "hindi",
+                "japanese", "korean", "latin", "russian"]
+N_CHARS = 1 << 17
 N_DOCS = 64            # packed-batch size
 SERVE_REQUESTS = 8
 
@@ -321,7 +327,6 @@ def main(argv=None) -> int:
 
     import jax
 
-    from benchmarks.transcode_bench import LIPSUM_LANGS, N_CHARS
     from repro.data import synthetic
     from repro.kernels import runtime
 

@@ -14,15 +14,15 @@ are deprecated shims that warn (DESIGN.md §11).  Migration table:
     utf16_to_utf8(u, n)                 transcode(u, "utf8", src_format="utf16", n_valid=n, strategy="blockparallel")
     utf8_to_utf32 / utf16_to_utf32      transcode(x, "utf32", src_format=..., strategy="blockparallel")
     utf32_to_utf8 / utf32_to_utf16      transcode(cp, ..., src_format="utf32", strategy="blockparallel")
-    utf8_to_latin1 / latin1_to_*        transcode(x, ..., strategy="fused")
+    utf8_to_latin1 / latin1_to_*        transcode(x, ..., strategy="onepass")
     scan_utf8(b, n)                     scan(b, "utf16", src_format="utf8", n_valid=n)
     scan_utf16(u, n)                    scan(u, "utf8", src_format="utf16", n_valid=n)
     ragged_utf8_to_utf16(d, o, l)       ragged_transcode(d, o, l, src_format="utf8", dst_format="utf16")
     ragged_utf16_to_utf8(d, o, l)       ragged_transcode(d, o, l, src_format="utf16", dst_format="utf8")
     ragged_scan_utf8 / ragged_scan_utf16  ragged_scan(d, o, l, src_format=..., dst_format=...)
 
-(The ``strategy=`` column records each wrapper's historical default; the
-generic default is ``"onepass"``.)
+(The ``strategy=`` column records each wrapper's default; the generic
+default is ``"onepass"``.)
 """
 
 import numpy as np
@@ -51,9 +51,9 @@ def main():
     # --- UTF-8 -> UTF-16 (all strategies) -------------------------------
     # "onepass" (the default) is the single-launch pipeline: one read +
     # one decode of the input, inter-tile offsets carried in SMEM
-    # (DESIGN.md §9); "fused" is the two-launch kernel reference it is
-    # pinned bit-for-bit against.
-    for strat in ("onepass", "fused", "blockparallel", "windowed"):
+    # (DESIGN.md §9); "blockparallel" (pure jnp) and "windowed" (the
+    # paper's algorithm) are the references it is pinned against.
+    for strat in ("onepass", "blockparallel", "windowed"):
         out, cnt, err = tc.transcode(
             jnp.asarray(utf8), "utf16", src_format="utf8",
             n_valid=len(utf8), strategy=strat)
@@ -108,9 +108,8 @@ def main():
          bytes(np.asarray(out)[: int(cnt)].astype(np.uint8))
          == "café ÿ £".encode("utf-8"))
     out, cnt, status = tc.transcode(
-        jnp.asarray(utf8), "utf32", src_format="utf8", n_valid=len(utf8),
-        strategy="fused")
-    show("utf8 -> utf32 code points (fused cell)",
+        jnp.asarray(utf8), "utf32", src_format="utf8", n_valid=len(utf8))
+    show("utf8 -> utf32 code points (onepass cell)",
          np.array_equal(np.asarray(out)[: int(cnt)].astype(np.int64),
                         np.array([ord(c) for c in s])))
     out, cnt, status = tc.transcode(

@@ -15,11 +15,11 @@ way XLA lowers efficiently.  Both forms are provided:
 Both are jit-safe: output capacity is static, the logical length is returned
 as a scalar.
 
-The fused two-pass pipeline (DESIGN.md §5) replaces the *global* cumsum +
-scatter with hierarchical compaction: an intra-tile scan inside the Pallas
-kernel (:func:`tile_exclusive_scan`) plus a tiny inter-tile scan over one
-scalar per tile (:func:`tile_base_offsets`).  Only the two helpers below
-ever see per-tile state; no full-capacity index array is materialized.
+The Pallas kernels (DESIGN.md §5, §9) replace the *global* cumsum +
+scatter with hierarchical compaction: an intra-tile scan inside the kernel
+(:func:`tile_exclusive_scan`) plus a running output offset carried across
+the sequential grid, one scalar per tile.  No full-capacity index array
+is materialized.
 """
 
 from __future__ import annotations
@@ -110,18 +110,6 @@ def tile_exclusive_scan(x: jax.Array, rows: int = 8):
     else:
         total = (excl + x2)[..., nrow - 1:, nlane - 1:]
     return (excl.reshape(x.shape) if flat else excl), total
-
-
-def tile_base_offsets(tile_totals: jax.Array):
-    """Exclusive scan over per-tile totals -> (base_offsets, grand_total).
-
-    This is the only inter-tile coordination the fused pipeline needs: an
-    ``nblk``-element cumsum (one scalar per tile, not one per element).
-    """
-    base = jnp.cumsum(tile_totals) - tile_totals
-    total = (base[-1] + tile_totals[-1]) if tile_totals.shape[0] > 0 \
-        else jnp.int32(0)
-    return base, total
 
 
 def compact_gather(values: jax.Array, mask: jax.Array, capacity: int, fill=0):

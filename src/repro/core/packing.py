@@ -1,12 +1,12 @@
 """Ragged document packing for single-launch batched transcoding.
 
-The padded-vmap batch path maps the single-document transcoder over a
-fixed-capacity ``[B, L]`` buffer: every document pays for ``L`` elements
-of grid dispatch no matter how short it is, and a batch of skewed
-lengths burns most of its tiles on padding.  The packed layout removes
-that tax: documents are concatenated into ONE flat narrow-dtype buffer
-and the fused count/write kernels run as a single grid launch over the
-whole batch (``repro.kernels.ragged_transcode``), with per-tile scalars
+Mapping the single-document transcoder over a fixed-capacity ``[B, L]``
+buffer would make every document pay for ``L`` elements of grid
+dispatch no matter how short it is, and a batch of skewed lengths would
+burn most of its tiles on padding.  The packed layout removes that tax:
+documents are concatenated into ONE flat narrow-dtype buffer and the
+ragged kernel runs as a single grid launch over the whole batch
+(``repro.kernels.ragged_transcode``), with per-tile scalars
 segment-reduced per document afterwards.
 
 Layout (the ``PackedDocs`` triple):
@@ -44,7 +44,7 @@ import jax.numpy as jnp
 
 from repro import spans
 
-# One VMEM tile of the fused/ragged kernels: 8 sublanes x 128 lanes.
+# One VMEM tile of the transcode kernels: 8 sublanes x 128 lanes.
 TILE = 1024
 
 

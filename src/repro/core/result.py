@@ -1,6 +1,6 @@
 """simdutf-style transcode result: (buffer, count, status).
 
-Every ``repro.core`` transcoder and the fused Pallas pipeline return a
+Every ``repro.core`` transcoder and the onepass Pallas pipeline return a
 :class:`TranscodeResult` — a NamedTuple (so it unpacks like the
 historical 3-tuple and traverses as a jax pytree under
 ``jit``/``vmap``/``lax.cond``) whose third element is an int32
@@ -92,8 +92,8 @@ def first_error_status(err_map, n):
 
     Only positions in the live region ``[0, n)`` count; returns
     ``STATUS_OK`` when the map is clean there.  The single definition of
-    the reduce every strategy (blockparallel, windowed, the fused
-    wrappers' per-tile variant) derives its status from.
+    the reduce every strategy (blockparallel, windowed, the kernels'
+    per-tile variant) derives its status from.
     """
     idx = jnp.arange(err_map.shape[0])
     errpos = jnp.where(err_map & (idx < n), idx, NO_ERR_SENTINEL)
@@ -105,7 +105,7 @@ def status_from_first(first_index, err_any=None):
     an optional independent error flag into one int32 status.
 
     ``err_any`` is a belt-and-braces flag from a second detector (the
-    Keiser-Lemire nibble tables in the fused count pass): if it fires
+    Keiser-Lemire nibble tables in the kernels' count): if it fires
     without a located position — the detectors are equivalent, so this
     should never happen — the status degrades to offset 0 rather than
     silently reporting a valid stream.

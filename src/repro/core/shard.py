@@ -202,7 +202,7 @@ def plan_shards(data, offsets, lengths, n_shards: int, *,
 # shard_map execution: one UNCHANGED ragged onepass launch per shard.
 
 # Jitted shard_map callables, keyed per (mesh devices, cell, policy,
-# donate) — the ``_BATCH_CACHE`` LRU pattern (shapes re-key inside jit).
+# donate), LRU-bounded (shapes re-key inside jit).
 _CALL_CACHE: dict = {}
 _CALL_CACHE_MAX = 16
 

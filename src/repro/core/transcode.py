@@ -16,8 +16,8 @@ The codec matrix (DESIGN.md §8): :func:`transcode` dispatches any
 ``(src_format, dst_format)`` pair over the ``utf8`` / ``utf16`` / ``utf32``
 / ``latin1`` formats — every pair runs through ONE generic decode×encode
 composition per strategy (the stage driver of ``repro.kernels.stages`` on
-the fused path, the shared speculative-decode + global-compaction body on
-the block-parallel path).  Format names accept the codecs-module aliases
+the onepass path, the shared speculative-decode + global-compaction body
+on the block-parallel path).  Format names accept the codecs-module aliases
 (``"utf-8"``, ``"utf-16-le"``, ``"utf-32-le"``, ``"latin-1"`` /
 ``"iso-8859-1"``).
 
@@ -37,12 +37,9 @@ Strategies (the ``strategy=`` kwarg; full decision table in DESIGN.md §5):
   * ``onepass`` (matrix + per-doc default) -- single-launch Pallas
     pipeline (DESIGN.md §9): one read + one decode of the input, with
     the inter-tile output offsets carried as a scalar in SMEM across the
-    sequential grid and a per-tile ASCII fast path.  Bit-identical to
-    ``fused``.
-  * ``fused``  -- two-pass Pallas pipeline (count launch + inter-launch
-    cumsum + write launch) with hierarchical in-kernel compaction and
-    narrow (uint8/uint16/uint32) I/O; validation is folded into the
-    counting scan.  The kernel reference ``onepass`` is pinned against.
+    sequential grid, hierarchical in-kernel compaction, narrow
+    (uint8/uint16/uint32) I/O, validation folded into the decode, and a
+    per-block ASCII fast path.
   * ``blockparallel``    -- speculative per-position decode + global XLA
     cumsum compaction; fully branch-free, pure-jnp (no Pallas), the
     portable beyond-paper form and the semantic reference.
@@ -135,16 +132,15 @@ CAP_FACTOR = {
 PAIRS = tuple(sorted(CAP_FACTOR))
 
 # The strategy registry: every name `transcode` dispatches, in preference
-# order.  `onepass` is the default (single launch, single decode);
-# `fused` stays selectable as the two-pass kernel reference; the scan
-# entry points accept the same names (onepass/fused share one counting
-# kernel there).
-STRATEGIES = ("onepass", "fused", "blockparallel", "windowed")
+# order.  `onepass` is the default (single launch, single decode) and the
+# one device path; `blockparallel` (pure jnp) and `windowed` (the paper's
+# algorithm) are the references it is pinned against.
+STRATEGIES = ("onepass", "blockparallel", "windowed")
 
 # The ragged (packed-batch) entry point additionally accepts "sharded":
 # the packed stream split across a device mesh's data axis with one
 # onepass launch per shard (repro.core.shard, DESIGN.md §12).
-RAGGED_STRATEGIES = ("onepass", "fused", "sharded")
+RAGGED_STRATEGIES = ("onepass", "sharded")
 
 DEFAULT_STRATEGY = "onepass"
 
@@ -155,7 +151,9 @@ DEFAULT_STRATEGY = "onepass"
 # ``DeprecationWarning`` attributed to ITS CALLER (stacklevel past the
 # shim), so CI can run with ``-W error::DeprecationWarning:repro`` and
 # fail on internal use while external callers merely see the warning.
-# The shims preserve their historical default strategies bit-for-bit.
+# The shims keep their historical default strategies, except that those
+# whose default was the retired two-launch kernel ("fused") default to
+# "onepass", which gives bit-identical results.
 DEPRECATED = (
     "utf8_to_utf16", "utf8_to_utf32", "utf8_to_latin1",
     "latin1_to_utf8", "latin1_to_utf16",
@@ -207,7 +205,7 @@ def validate_utf16(u, n_valid=None):
 # ---------------------------------------------------------------------------
 # Block-parallel matrix body: whole-array speculative decode + analysis
 # per source format, candidate production per destination format, global
-# XLA compaction.  This is the pure-jnp semantic reference every fused
+# XLA compaction.  This is the pure-jnp semantic reference every onepass
 # cell is pinned to bit-for-bit.
 
 
@@ -300,7 +298,7 @@ def _blockparallel_pair(x, n_valid, src: str, dst: str, validate: bool,
         return general(x)
     # The lower bound matters: lanes are int32 here, so a garbage UTF-32
     # scalar like 0xFFFFFFFF wraps negative and would pass a bare
-    # ``x < 0x80`` (the fused path compares in the unsigned narrow dtype
+    # ``x < 0x80`` (the onepass path compares in the unsigned narrow dtype
     # and needs no guard).
     return jax.lax.cond(jnp.all((x >= 0) & (x < 0x80)), ascii, general, x)
 
@@ -348,14 +346,13 @@ def scan(x, dst_format, *, src_format: str = "utf8", n_valid=None,
     src = normalize_format(src_format)
     dst = normalize_format(dst_format)
     _check_pair(src, dst)
-    if strategy in ("onepass", "fused"):
-        # The counting pass is already single-launch/single-read — the
-        # one-pass strategy's scan IS the fused scan (see
-        # repro.kernels.onepass_transcode.scan_onepass).
-        from repro.kernels import fused_transcode
-        return fused_transcode.scan_fused(x, n_valid, src=src, dst=dst)
+    if strategy == "onepass":
+        from repro.kernels import onepass_transcode
+        return onepass_transcode.scan_onepass(x, n_valid, src=src, dst=dst)
     if strategy != "blockparallel":
-        raise ValueError(f"scan: unknown strategy {strategy!r}")
+        raise ValueError(
+            f"scan: unknown strategy {strategy!r} "
+            f"(supported: ['onepass', 'blockparallel'])")
     return _blockparallel_count(x, n_valid, src, dst)
 
 
@@ -401,9 +398,9 @@ def utf8_to_utf16(b, n_valid=None, validate: bool = True,
 
 
 def utf8_to_latin1(b, n_valid=None, validate: bool = True,
-                   errors: str = "strict", *, strategy: str = "fused"):
+                   errors: str = "strict", *, strategy: str = "onepass"):
     """DEPRECATED shim: use :func:`transcode` (``dst_format="latin1"``).
-    Historical default strategy: ``fused``."""
+    Default strategy: ``onepass`` (historically ``fused``)."""
     _warn_deprecated("utf8_to_latin1",
                      'transcode(b, "latin1", src_format="utf8")')
     return transcode(b, "latin1", src_format="utf8", n_valid=n_valid,
@@ -411,9 +408,9 @@ def utf8_to_latin1(b, n_valid=None, validate: bool = True,
 
 
 def latin1_to_utf8(b, n_valid=None, validate: bool = True,
-                   errors: str = "strict", *, strategy: str = "fused"):
+                   errors: str = "strict", *, strategy: str = "onepass"):
     """DEPRECATED shim: use :func:`transcode` (``src_format="latin1"``).
-    Historical default strategy: ``fused``."""
+    Default strategy: ``onepass`` (historically ``fused``)."""
     _warn_deprecated("latin1_to_utf8",
                      'transcode(b, "utf8", src_format="latin1")')
     return transcode(b, "utf8", src_format="latin1", n_valid=n_valid,
@@ -421,9 +418,9 @@ def latin1_to_utf8(b, n_valid=None, validate: bool = True,
 
 
 def latin1_to_utf16(b, n_valid=None, validate: bool = True,
-                    errors: str = "strict", *, strategy: str = "fused"):
+                    errors: str = "strict", *, strategy: str = "onepass"):
     """DEPRECATED shim: use :func:`transcode` (``src_format="latin1"``).
-    Historical default strategy: ``fused``."""
+    Default strategy: ``onepass`` (historically ``fused``)."""
     _warn_deprecated("latin1_to_utf16",
                      'transcode(b, "utf16", src_format="latin1")')
     return transcode(b, "utf16", src_format="latin1", n_valid=n_valid,
@@ -570,10 +567,10 @@ def cps_to_utf32le_bytes(cp):
 
 
 # ---------------------------------------------------------------------------
-# Strategy dispatch (onepass = single-launch Pallas, fused = two-pass
-# Pallas, windowed = paper-faithful; kernels imported lazily to avoid
-# circular imports).  The STRATEGIES registry and DEFAULT_STRATEGY live
-# next to the format registry above.
+# Strategy dispatch (onepass = single-launch Pallas, windowed =
+# paper-faithful; kernels imported lazily to avoid circular imports).
+# The STRATEGIES registry and DEFAULT_STRATEGY live next to the format
+# registry above.
 
 # The serial paper baseline exists for the paper's own two directions.
 _WINDOWED_PAIRS = {("utf8", "utf16"), ("utf16", "utf8")}
@@ -599,10 +596,6 @@ def transcode(src, dst_format, *, src_format: str = "utf8", n_valid=None,
     if strategy == "onepass":
         from repro.kernels import onepass_transcode
         return onepass_transcode.transcode_onepass(
-            src, n_valid, src=s, dst=d, validate=validate, errors=errors)
-    elif strategy == "fused":
-        from repro.kernels import fused_transcode
-        return fused_transcode.transcode_fused(
             src, n_valid, src=s, dst=d, validate=validate, errors=errors)
     elif strategy == "blockparallel":
         return _blockparallel_pair(src, n_valid, s, d, validate, errors)
@@ -658,13 +651,11 @@ def ragged_transcode(data, offsets, lengths, *, src_format: str = "utf8",
     batch over a :func:`repro.core.packing.pack_documents` layout.
 
     Returns a :class:`repro.core.result.RaggedTranscodeResult` whose
-    per-document slices are bit-identical to the single-document fused
+    per-document slices are bit-identical to the single-document
     transcoder; ``errors=`` carries the usual strict/replace policy per
-    document.  This is the padding-tax-free batch path (DESIGN.md §7) —
-    the padded ``vmap`` form survives in ``repro.data.pipeline`` as the
-    reference.  ``strategy="onepass"`` (default) is the single-pass
-    launch with the segment scan carried in SMEM (DESIGN.md §9);
-    ``strategy="fused"`` keeps the two-launch kernel reference;
+    document.  This is the padding-tax-free batch path (DESIGN.md §7).
+    ``strategy="onepass"`` (default) is the single-pass launch with the
+    segment scan carried in SMEM (DESIGN.md §9);
     ``strategy="sharded"`` splits the packed batch across the data axis
     of a device mesh with one onepass launch per shard (DESIGN.md §12 —
     ``n_shards`` / ``shard_mesh`` / ``chunk_budget`` apply only there)
@@ -676,16 +667,17 @@ def ragged_transcode(data, offsets, lengths, *, src_format: str = "utf8",
             data, offsets, lengths, src_format=src_format,
             dst_format=dst_format, validate=validate, errors=errors,
             n_shards=n_shards, mesh=shard_mesh, chunk_budget=chunk_budget)
+    if strategy != "onepass":
+        raise ValueError(
+            f"ragged_transcode: unknown strategy {strategy!r} "
+            f"(supported: {list(RAGGED_STRATEGIES)})")
     if n_shards is not None or shard_mesh is not None:
         raise ValueError(
             "n_shards/shard_mesh require strategy='sharded'")
-    # Single-device strategy validation lives in ONE layer (the kernel
-    # dispatch below).
     from repro.kernels import ragged_transcode as rt
     return rt.transcode_ragged(
         data, offsets, lengths, src=normalize_format(src_format),
-        dst=normalize_format(dst_format), validate=validate, errors=errors,
-        strategy=strategy)
+        dst=normalize_format(dst_format), validate=validate, errors=errors)
 
 
 def ragged_scan(data, offsets, lengths, *, src_format: str = "utf8",

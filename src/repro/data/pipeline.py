@@ -40,61 +40,26 @@ from repro.data.tokenizer import BOS_ID, EOS_ID, PAD_ID, ByteTokenizer
 # ---------------------------------------------------------------------------
 # Batched transcoding entry points.
 #
-# Two batch geometries share one API (inputs: fixed-capacity [B, L]
-# buffers of narrow dtype plus a [B] vector of logical lengths; outputs:
-# a TranscodeResult of batched arrays — [B, cap] buffers, [B] counts,
-# [B] statuses, per-document first-error offsets, -1 where valid):
-#
-#   * ``strategy="packed"`` (default) — the ragged packed path: the
-#     [B, L] buffer is reinterpreted as ONE tile-aligned packed stream
-#     (row-major flattening IS the packed layout once L is padded to a
-#     tile multiple) and the single-pass kernel runs as ONE grid launch
-#     for the whole batch (DESIGN.md §7/§9 — the default ragged strategy
-#     is "onepass": one read + one decode, segment scan carried in
-#     SMEM); the dense ragged output is re-padded to the [B, cap]
-#     contract with one gather.  Callers that can consume the dense
-#     layout directly should use ``tc.ragged_transcode`` on a
-#     ``packing.pack_documents`` batch and skip both the padding and the
-#     re-pad gather.
-#   * ``strategy="vmap"`` — the padded reference: ``jax.vmap`` of the
-#     single-document default (one-pass) transcoder over the document
-#     axis (B grid dispatches, every document scans all of L).  A
-#     per-document strategy name ("onepass" / "fused" / "blockparallel"
-#     / "windowed") selects that transcoder under vmap, as before.
+# Inputs are fixed-capacity [B, L] buffers of narrow dtype plus a [B]
+# vector of logical lengths; outputs are a TranscodeResult of batched
+# arrays — [B, cap] buffers, [B] counts, [B] statuses, per-document
+# first-error offsets, -1 where valid.  The [B, L] buffer is
+# reinterpreted as ONE tile-aligned packed stream (row-major flattening
+# IS the packed layout once L is padded to a tile multiple) and the
+# ragged single-pass kernel runs as ONE grid launch for the whole batch
+# (DESIGN.md §7/§9: one read + one decode, segment scan carried in
+# SMEM), on one device ("packed") or split across a device mesh's data
+# axis ("sharded", DESIGN.md §12); the dense ragged output is re-padded
+# to the [B, cap] contract with one gather.  Callers that can consume
+# the dense layout directly should use ``tc.ragged_transcode`` on a
+# ``packing.pack_documents`` batch and skip both the padding and the
+# re-pad gather.
 #
 # The ``errors=`` policy threads through both, so a batch of partially-
 # malformed documents can ingest losslessly (errors="replace": U+FFFD
 # per maximal subpart) without a host round trip.
 
-# Jitted vmap callables, keyed per (pair, strategy, validate,
-# errors, capacity).  Capacity is part of the key: a [B, L] batch
-# compiles per distinct L anyway (shapes are static), so an unkeyed
-# entry would silently accumulate one trace per capacity inside a
-# single cache slot with nothing bounding the set.  Keying + the LRU
-# bound below make the retrace budget explicit and bounded.
-_BATCH_CACHE: "dict" = {}
-_BATCH_CACHE_MAX = 16
-
-
-def _batched(src: str, dst: str, strategy: str, validate: bool, errors: str,
-             capacity: int):
-    key = (src, dst, strategy, validate, errors, capacity)
-    fn = _BATCH_CACHE.get(key)
-    if fn is None:
-        fn = jax.jit(jax.vmap(
-            lambda x, n: tc.transcode(x, dst, src_format=src, n_valid=n,
-                                      strategy=strategy, validate=validate,
-                                      errors=errors)))
-        while len(_BATCH_CACHE) >= _BATCH_CACHE_MAX:
-            _BATCH_CACHE.pop(next(iter(_BATCH_CACHE)))
-        _BATCH_CACHE[key] = fn
-    else:
-        # LRU refresh: dicts iterate in insertion order, so re-inserting
-        # on hit keeps hot entries at the back and evicts the coldest.
-        _BATCH_CACHE.pop(key)
-        _BATCH_CACHE[key] = fn
-    return fn
-
+BATCH_STRATEGIES = ("packed", "sharded")
 
 _TILE = packing.TILE
 
@@ -146,9 +111,7 @@ def batch_transcode(docs, lengths, *, in_encoding: str = "utf8",
     launch; ``strategy="sharded"`` splits that same packed stream across
     the data axis of a device mesh — one onepass launch per shard,
     bit-identical gather (DESIGN.md §12; ``n_shards`` applies only
-    here); ``strategy="vmap"`` maps the single-document default
-    (one-pass) transcoder over the document axis (a per-document
-    strategy name selects that transcoder under vmap instead).
+    here).
     """
     faults.fire(faults.PIPELINE_BATCH)   # chaos-suite hook (no-op in prod)
     src = tc.normalize_format(in_encoding)
@@ -156,6 +119,10 @@ def batch_transcode(docs, lengths, *, in_encoding: str = "utf8",
     if (src, dst) not in tc.CAP_FACTOR:
         raise ValueError(f"unsupported format pair {src!r} -> {dst!r}")
     factor = tc.CAP_FACTOR[(src, dst)]
+    if strategy not in BATCH_STRATEGIES:
+        raise ValueError(
+            f"batch_transcode: unknown strategy {strategy!r} "
+            f"(supported: {list(BATCH_STRATEGIES)})")
     if n_shards is not None and strategy != "sharded":
         raise ValueError("n_shards requires strategy='sharded'")
     docs = jnp.asarray(docs)
@@ -172,14 +139,10 @@ def batch_transcode(docs, lengths, *, in_encoding: str = "utf8",
             src_format=src, dst_format=dst, validate=validate,
             errors=errors, strategy="sharded", n_shards=n_shards)
         return _repad(res, factor * docs.shape[1])
-    if strategy == "packed":
-        from repro.kernels import stages
-        narrow = docs.astype(stages.get_codec(src).dtype)
-        return _packed_batch(narrow, lengths, src, dst, validate, errors,
-                             factor * docs.shape[1])
-    per_doc = tc.DEFAULT_STRATEGY if strategy == "vmap" else strategy
-    return _batched(src, dst, per_doc, validate, errors,
-                    docs.shape[1])(docs, lengths)
+    from repro.kernels import stages
+    narrow = docs.astype(stages.get_codec(src).dtype)
+    return _packed_batch(narrow, lengths, src, dst, validate, errors,
+                         factor * docs.shape[1])
 
 
 def batch_utf8_to_utf16(docs, lengths, *, strategy: str = "packed",
@@ -202,7 +165,7 @@ def batch_utf8_to_codepoints(docs, lengths, *, strategy: str = "packed",
                              validate: bool = True,
                              errors: str = "strict"):
     """Batched UTF-8 -> UTF-32 code points: the device-side decode the
-    codepoint-consuming models ingest (one fused/ragged launch, not the
+    codepoint-consuming models ingest (one ragged launch, not the
     host-side ``core/utf32.py`` helpers)."""
     return batch_transcode(docs, lengths, in_encoding="utf8",
                            out_encoding="utf32", strategy=strategy,
@@ -220,7 +183,7 @@ class PipelineConfig:
     validate: bool = True
     # "tokens" (default): byte-tokenized BOS/doc/EOS frames.
     # "codepoints": the batch additionally carries per-document UTF-32
-    # code points, decoded ON DEVICE through the fused/ragged
+    # code points, decoded ON DEVICE through the ragged
     # UTF-8 -> UTF-32 matrix cell (one packed launch per batch — not the
     # host-side core/utf32.py helpers).
     emit: str = "tokens"
@@ -309,7 +272,7 @@ class TextPipeline:
         if cfg.emit == "codepoints":
             # Device-side decode to the UTF-32 interchange format: ONE
             # ragged packed launch for the whole local batch through the
-            # fused UTF-8 -> UTF-32 matrix cell.
+            # UTF-8 -> UTF-32 matrix cell.
             res = batch_utf8_to_codepoints(
                 np.stack(raws), np.asarray(lens, np.int32),
                 validate=cfg.validate)

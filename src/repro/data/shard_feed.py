@@ -19,7 +19,7 @@ the kernel time (``compute_s``) and the residual wait the main thread
 actually paid after its kernel finished (``stall_s``).  The
 transfer-hidden fraction — ``1 - sum(stall)/sum(transfer)`` over the
 steady-state waves (the first wave has no kernel to hide behind) — is
-the ``table_shard`` bench's gated metric.
+what ``tests/test_shard.py`` holds the feeder to.
 
 Buffer donation: the launch callables built by
 :func:`repro.core.shard.sharded_call` with ``donate=True`` donate the

@@ -25,8 +25,6 @@ from repro.kernels import runtime
 from repro.kernels import utf8_decode as kdec
 from repro.kernels import utf8_validate as kval
 from repro.kernels import utf16_encode as kenc
-from repro.kernels.fused_transcode import (  # noqa: F401  (re-export)
-    utf8_to_utf16_fused, utf16_to_utf8_fused)
 
 ROWS, LANES, BLOCK = kdec.ROWS, kdec.LANES, kdec.BLOCK
 

@@ -1,38 +1,33 @@
 """Ragged packed-batch transcode: one Pallas launch for a whole batch,
 any cell of the codec matrix.
 
-The padded-vmap batch path (``data/pipeline.py`` ``strategy="vmap"``)
-maps the single-document fused pipeline over a ``[B, L]`` buffer: B
-separate grid dispatches, and every document — however short — scans all
-``ceil(L/1024)`` of its tiles.  The packed path removes both costs.
 Documents are concatenated into ONE tile-aligned narrow buffer
-(``repro.core.packing``), and the *same* generic count/write tile bodies
+(``repro.core.packing``), and the *same* generic tile bodies
 (``repro.kernels.stages.driver``) run as a single grid launch over the
-packed stream.  Per-document bookkeeping is all per-tile scalars:
+packed stream, so no document scans the padding of a fixed-width
+``[B, L]`` batch and the batch pays one dispatch.  Per-document
+bookkeeping is all per-tile scalars:
 
   Ownership map    ``packing.tile_ownership`` (on device): tile ->
                    owning document (a searchsorted over the [B+1] offset
                    vector), the tile's document-end offset (the live
                    mask), and same-document neighbour flags.
 
-  Count pass       One grid launch over all tiles of all documents.
-                   The kernels differ from the single-stream ones in
+  One launch       The kernel differs from the single-stream one in
                    precisely two multiplies: neighbour-tile inflow is
                    zeroed when the neighbour belongs to a different
                    document (``xp * same_prev`` / ``xn * same_next`` —
                    a character must never claim elements across a
                    document boundary), and the live mask compares
-                   against the tile's own document end.
-
-  Segment scan     The per-tile totals feed the SAME nblk-element
-                   exclusive cumsum as the single-stream pipeline
-                   (``compaction.tile_base_offsets``): because documents
-                   are packed in order, the dense global scan IS the
-                   per-document segment scan.
-
-  Write pass       One grid launch; each tile compacts in VMEM and
-                   stores at ``base[tile]`` exactly as the single-stream
-                   writer.
+                   against the tile's own document end.  Count and
+                   write run off ONE decode per tile, with the output
+                   offset carried as a scalar in SMEM scratch across the
+                   sequential grid (DESIGN.md §9): because documents are
+                   packed in order, the global running offset IS the
+                   per-document segment scan.  Per-tile ``(total, err,
+                   ferr)`` scalars still leave the kernel — they are the
+                   *product* (the per-document reduction consumes them),
+                   not inter-pass coordination.
 
   Per-doc reduce   counts = segment_sum(totals), error flags
                    segment_max, first-error offsets segment_min (the
@@ -44,33 +39,24 @@ packed stream.  Per-document bookkeeping is all per-tile scalars:
 
 Status/errors semantics are exactly :class:`repro.core.result.
 TranscodeResult`'s, per document.  Every document's output slice is
-bit-identical to running the single-document fused transcoder on that
+bit-identical to running the single-document transcoder on that
 document alone (pinned by ``tests/test_differential.py``).
 
-The description above is the two-launch (``strategy="fused"``) form.
-The DEFAULT is now ``strategy="onepass"`` (DESIGN.md §9): the count and
-write bodies run in ONE grid launch off ONE decode per tile, with the
-inter-tile/segment scan carried as a scalar in SMEM scratch across the
-sequential grid — because documents are packed in order, the global
-running offset IS the per-document segment scan, and the per-document
-ownership masks (cross-document inflow zeroing + per-tile live ends) are
-exactly the "per-tile ownership resets" the carry needs.  Per-tile
-``(total, err, ferr)`` scalars still leave the kernel — they are the
-*product* (the per-document segment reductions consume them), not
-inter-pass coordination.
+Like the single-stream kernel, each grid step handles a block of G
+consecutive tiles, ``(G, ROWS, LANES)``, G from the batch's tile count
+(``window.tiles_per_step`` up to ``RAGGED_G_MAX``).  A block usually
+spans several documents, so ownership stays per tile inside it: tile
+j's neighbour inflow is zeroed by its own ``same_prev`` /
+``same_next``, its live mask ends at its own document's end, and its
+``(total, err, ferr)`` come out on their own instead of folded over the
+block.  The three-way class dispatch (ASCII copy / narrowed ≤2-byte
+body / general, DESIGN.md §9) is taken once per block: a block takes a
+class only when each of its tiles would, so an ASCII document that
+shares a block with a CJK one runs the general body there, with the
+same result.
 
-Like the single-stream one-pass kernel, each grid step handles a block
-of G consecutive tiles, ``(G, ROWS, LANES)``, G from the batch's tile
-count (``onepass_transcode.tiles_per_step`` up to ``RAGGED_G_MAX``).
-A block usually spans several documents, so ownership stays per tile
-inside it: tile j's neighbour inflow is zeroed by its own
-``same_prev`` / ``same_next``, its live mask ends at its own document's
-end, and its ``(total, err, ferr)`` come out on their own instead of
-folded over the block.  The three-way class dispatch (ASCII copy /
-narrowed ≤2-byte body / general, DESIGN.md §9) is taken once per block:
-a block takes a class only when each of its tiles would, so an ASCII
-document that shares a block with a CJK one runs the general body
-there, with the same result.
+:func:`scan_ragged` is the counting kernel alone, one tile per grid
+step, with the same ownership masking and per-document reduction.
 """
 
 from __future__ import annotations
@@ -85,29 +71,35 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro import spans
-from repro.core import compaction, packing
+from repro.core import packing
 from repro.core import result as R
-from repro.kernels import fused_transcode as ft
-from repro.kernels import onepass_transcode as op
 from repro.kernels import runtime
 from repro.kernels import stages
+from repro.kernels import window
 from repro.kernels.stages import driver as sdrv
 from repro.testing import faults
 
-ROWS = ft.ROWS
-LANES = ft.LANES
-BLOCK = ft.BLOCK
+ROWS = window.ROWS
+LANES = window.LANES
+BLOCK = window.BLOCK
 
 _IMAX = R.NO_ERR_SENTINEL
-
-_SMEM = ft._SMEM
-_tile_spec = ft._tile_spec
 
 _check_errors = R.check_errors_policy
 
 # The single-pass launch's name, in the profiler trace of the chip as in
 # the HLO.
 ONEPASS_KERNEL_NAME = "ragged_onepass_transcode"
+
+# Most 1 KiB tiles one grid step handles (a power of two).  Blocks span
+# documents, so nearly every block of a packed batch takes the general
+# body.  On the chip, per 1,024-tile batch of packed UTF-8 documents to
+# UTF-16 with errors="replace": 3.265 ms at G=1, 0.828 at 8, 0.757 at
+# 16, 0.743 at 32, compiling in 2.0, 3.5, 5.6 and 10.3 s.  G=16 is
+# within 3% of the best, but its compile raised the batch program's
+# set-up by 16%; G=8 gives up 9% of kernel time, about 1% of a batch
+# (PERF.md).
+RAGGED_G_MAX = 8
 
 
 def _nblk(total: int) -> int:
@@ -150,8 +142,8 @@ def _doc_reduce(totals, errs, ferrs, tile_doc, offsets, validate):
 
 
 # ---------------------------------------------------------------------------
-# Generic ragged kernels: the single-stream generic bodies plus the
-# ownership masking (cross-document neighbour inflow zeroed).
+# Ownership masking (cross-document neighbour inflow zeroed), shared by
+# the counting kernel and the single-pass kernel.
 
 
 def _owned_tiles(end_ref, sp_ref, sn_ref, xp_ref, x_ref, xn_ref):
@@ -162,8 +154,8 @@ def _owned_tiles(end_ref, sp_ref, sn_ref, xp_ref, x_ref, xn_ref):
     mask ends at its own document's end.  Returns ``(x, xp, xn, live,
     gidx)``."""
     i = pl.program_id(0)
-    x, xp, xn = op._load_block(xp_ref, x_ref, xn_ref)
-    gidx = ft._gidx(x.shape)
+    x, xp, xn = window.load_block(xp_ref, x_ref, xn_ref)
+    gidx = window.gidx(x.shape)
     if x.ndim == 2:
         return x, xp * sp_ref[i], xn * sn_ref[i], gidx < end_ref[i], gidx
     g = x.shape[0]
@@ -178,38 +170,24 @@ def _owned_tiles(end_ref, sp_ref, sn_ref, xp_ref, x_ref, xn_ref):
 
 
 def _rcount_kernel(end_ref, sp_ref, sn_ref, xp_ref, x_ref, xn_ref,
-                   tot_ref, err_ref, ferr_ref, *, src, dst, errors,
-                   validate):
+                   tot_ref, err_ref, ferr_ref, *, src, dst):
     codec_s, codec_d = stages.get_codec(src), stages.get_codec(dst)
     i = pl.program_id(0)
     x, xp, xn, live, gidx = _owned_tiles(end_ref, sp_ref, sn_ref, xp_ref,
                                          x_ref, xn_ref)
     tot_ref[i], err_ref[i], ferr_ref[i] = sdrv.count_tile(
-        codec_s, codec_d, x, xp, xn, live, gidx, errors=errors,
-        validate=validate)
-
-
-def _rwrite_kernel(end_ref, sp_ref, sn_ref, base_ref, tot_ref,
-                   xp_ref, x_ref, xn_ref, out_ref, buf, pend, sem, *, src,
-                   dst, errors):
-    codec_s, codec_d = stages.get_codec(src), stages.get_codec(dst)
-    i = pl.program_id(0)
-    x, xp, xn, live, _gidx = _owned_tiles(end_ref, sp_ref, sn_ref, xp_ref,
-                                          x_ref, xn_ref)
-    stage = sdrv.write_stage(codec_s, codec_d, x, xp, xn, live,
-                             errors=errors)
-    ft.store_window(stage, base_ref[i], tot_ref[i], out_ref, buf, pend, sem)
+        codec_s, codec_d, x, xp, xn, live, gidx, errors="strict",
+        validate=True)
 
 
 def _launch_geometry(data, offsets, lengths, g=1):
-    """ONE definition of the ragged launch setup, shared by every ragged
-    kernel call (count/write/onepass): the ownership map, the masked +
-    boundary-tiled data, and the matching in_specs/operand prefix.
-    Desynchronizing these between the bodies would compute base offsets
-    on a different tiling than the writer stores with.
+    """ONE definition of the ragged launch setup, shared by both ragged
+    kernel calls (count/onepass): the ownership map, the masked +
+    boundary-tiled data, and the matching in_specs/operand prefix, so
+    that a scan counts exactly the tiles a transcode writes.
 
     With ``g > 1`` the data is tiled in blocks of ``g`` tiles (as
-    ``op._block_specs(g)`` reads it) and the ownership vectors are
+    ``window.block_specs(g)`` reads it) and the ownership vectors are
     padded to whole blocks with dead tiles (``tile_end`` 0, no
     neighbours).  Returns ``(nblk, tile_doc, owned, d3)``, ``nblk`` the
     data's own tiles.
@@ -226,33 +204,31 @@ def _launch_geometry(data, offsets, lengths, g=1):
     return nblk, tile_doc, owned, d3
 
 
-_OWNED_SPECS = [_SMEM, _SMEM, _SMEM]
-_TILE_SPECS = [_tile_spec(0), _tile_spec(1), _tile_spec(2)]
+_OWNED_SPECS = [window.SMEM, window.SMEM, window.SMEM]
+_TILE_SPECS = [window.tile_spec(0), window.tile_spec(1), window.tile_spec(2)]
 
 
-def _rcount_call(data, offsets, lengths, src, dst, errors, validate,
-                 interpret):
+def _rcount_call(data, offsets, lengths, src, dst, interpret):
     nblk, tile_doc, owned, d3 = _launch_geometry(data, offsets, lengths)
-    ft.check_launch(nblk, stages.get_pair(src, dst)[2], 6, interpret,
-                    "ragged scan")
-    kernel = functools.partial(_rcount_kernel, src=src, dst=dst,
-                               errors=errors, validate=validate)
+    window.check_launch(nblk, stages.get_pair(src, dst)[2], 6, interpret,
+                        "ragged scan")
+    kernel = functools.partial(_rcount_kernel, src=src, dst=dst)
     per_tile = jax.ShapeDtypeStruct((nblk,), jnp.int32)
     totals, errs, ferrs = pl.pallas_call(
         kernel,
         grid=(nblk,),
         in_specs=_OWNED_SPECS + _TILE_SPECS,
-        out_specs=[_SMEM, _SMEM, _SMEM],
+        out_specs=[window.SMEM, window.SMEM, window.SMEM],
         out_shape=[per_tile, per_tile, per_tile],
-        compiler_params=ft._SEQUENTIAL,
+        compiler_params=window.SEQUENTIAL,
         interpret=interpret,
     )(*owned, d3, d3, d3)
-    return nblk, d3, tile_doc, owned, totals, errs, ferrs
+    return tile_doc, totals, errs, ferrs
 
 
 # ---------------------------------------------------------------------------
-# Single-pass ragged kernel (strategy="onepass", the default): count +
-# write in one grid launch off one decode, base offsets carried in SMEM.
+# Single-pass ragged kernel: count + write in one grid launch off one
+# decode, base offsets carried in SMEM.
 
 
 def _ronepass_kernel(end_ref, sp_ref, sn_ref, xp_ref, x_ref, xn_ref,
@@ -266,7 +242,7 @@ def _ronepass_kernel(end_ref, sp_ref, sn_ref, xp_ref, x_ref, xn_ref,
     def _init():
         carry[0] = 0
 
-    # Ownership masking, exactly as the two-launch kernels, tile by tile
+    # Ownership masking, exactly as the counting kernel, tile by tile
     # inside the block: cross-document neighbour inflow reads as zeros.
     # (The zeroed inflow also lets the ASCII predicate pass at document
     # starts — a document boundary is a clean inflow by construction.)
@@ -279,7 +255,7 @@ def _ronepass_kernel(end_ref, sp_ref, sn_ref, xp_ref, x_ref, xn_ref,
     # Documents are packed in order, so the global running offset IS the
     # per-document segment scan (dense output, no inter-doc padding).
     base = carry[0]
-    ft.store_window(stage, base, tot, out_ref, buf, pend, sem)
+    window.store_window(stage, base, tot, out_ref, buf, pend, sem)
     if g == 1:
         tot, err, ferr = (tot,), (err,), (ferr,)
     carry[0] = base + sum(tot)
@@ -298,25 +274,25 @@ def _ragged_onepass_impl(data, offsets, lengths, src, dst, validate,
                          interpret, errors):
     codec_s, codec_d, factor = stages.get_pair(src, dst)
     width = stages.stage_width(codec_s, codec_d)
-    g = op.tiles_per_step(data.shape[0], op.RAGGED_G_MAX)
+    g = window.tiles_per_step(data.shape[0], RAGGED_G_MAX)
     nblk, tile_doc, owned, d3 = _launch_geometry(data, offsets, lengths, g)
-    ft.check_launch(nblk, factor, 6, interpret, "ragged transcode")
+    window.check_launch(nblk, factor, 6, interpret, "ragged transcode")
     kernel = functools.partial(_ronepass_kernel, src=src, dst=dst,
                                errors=errors, validate=validate,
                                ascii_skip=True)
     padded = owned[0].shape[0]
     per_tile = jax.ShapeDtypeStruct((padded,), jnp.int32)
-    out_spec, out_shape, scratch = ft.window_out(padded, width,
-                                                 codec_d.dtype, g)
+    out_spec, out_shape, scratch = window.window_out(padded, width,
+                                                     codec_d.dtype, g)
     with jax.named_scope(ONEPASS_KERNEL_NAME):
         outp, totals, errs, ferrs = pl.pallas_call(
             kernel,
             grid=(padded // g,),
-            in_specs=_OWNED_SPECS + op._block_specs(g),
-            out_specs=[out_spec, _SMEM, _SMEM, _SMEM],
+            in_specs=_OWNED_SPECS + window.block_specs(g),
+            out_specs=[out_spec, window.SMEM, window.SMEM, window.SMEM],
             out_shape=[out_shape, per_tile, per_tile, per_tile],
             scratch_shapes=scratch + [pltpu.SMEM((1,), jnp.int32)],
-            compiler_params=ft._SEQUENTIAL,
+            compiler_params=window.SEQUENTIAL,
             interpret=interpret,
             name=ONEPASS_KERNEL_NAME,
         )(*owned, d3, d3, d3)
@@ -324,41 +300,8 @@ def _ragged_onepass_impl(data, offsets, lengths, src, dst, validate,
     # The block padding's dead tiles hold nothing.
     totals, errs, ferrs = totals[:nblk], errs[:nblk], ferrs[:nblk]
     total = jnp.sum(totals)
-    cap = factor * nblk * BLOCK
-    outp = outp[:cap]
-    outp = jnp.where(jnp.arange(cap) < total, outp,
-                     jnp.zeros((), codec_d.dtype))
-    counts, out_offsets, statuses = _doc_reduce(
-        totals, errs, ferrs, tile_doc, offsets, validate)
-    return R.RaggedTranscodeResult(outp, out_offsets, counts, statuses)
-
-
-@functools.partial(jax.jit, static_argnames=("src", "dst", "validate",
-                                             "interpret", "errors"))
-def _ragged_impl(data, offsets, lengths, src, dst, validate, interpret,
-                 errors):
-    codec_s, codec_d, factor = stages.get_pair(src, dst)
-    width = stages.stage_width(codec_s, codec_d)
-    nblk, d3, tile_doc, owned, totals, errs, ferrs = _rcount_call(
-        data, offsets, lengths, src, dst, errors, validate, interpret)
-    base, total = compaction.tile_base_offsets(totals)
-    out_spec, out_shape, scratch = ft.window_out(nblk, width, codec_d.dtype)
-    outp = pl.pallas_call(
-        functools.partial(_rwrite_kernel, src=src, dst=dst, errors=errors),
-        grid=(nblk,),
-        in_specs=_OWNED_SPECS + [_SMEM, _SMEM] + _TILE_SPECS,
-        out_specs=out_spec,
-        out_shape=out_shape,
-        scratch_shapes=scratch,
-        compiler_params=ft._SEQUENTIAL,
-        interpret=interpret,
-    )(*owned, base, totals, d3, d3, d3).reshape(-1)
-    # Same capacity budget per document as the padded-vmap path (its
-    # tile span); clear the write-window slack after the last tile.
-    cap = factor * nblk * BLOCK
-    outp = outp[:cap]
-    outp = jnp.where(jnp.arange(cap) < total, outp,
-                     jnp.zeros((), codec_d.dtype))
+    outp = window.clip_to_cap(outp, factor * nblk * BLOCK, total,
+                              codec_d.dtype)
     counts, out_offsets, statuses = _doc_reduce(
         totals, errs, ferrs, tile_doc, offsets, validate)
     return R.RaggedTranscodeResult(outp, out_offsets, counts, statuses)
@@ -366,8 +309,8 @@ def _ragged_impl(data, offsets, lengths, src, dst, validate, interpret,
 
 @functools.partial(jax.jit, static_argnames=("src", "dst", "interpret"))
 def _ragged_scan_impl(data, offsets, lengths, src, dst, interpret):
-    _nb, _d3, tile_doc, _owned, totals, errs, ferrs = _rcount_call(
-        data, offsets, lengths, src, dst, "strict", True, interpret)
+    tile_doc, totals, errs, ferrs = _rcount_call(
+        data, offsets, lengths, src, dst, interpret)
     counts, _oo, statuses = _doc_reduce(
         totals, errs, ferrs, tile_doc, offsets, True)
     return counts, statuses
@@ -416,7 +359,7 @@ def _as_packed(data, offsets, lengths, dtype):
 
 def transcode_ragged(data, offsets, lengths, *, src: str, dst: str,
                      validate: bool = True, errors: str = "strict",
-                     interpret=None, strategy: str = "onepass"):
+                     interpret=None):
     """Ragged packed-batch transcode for any (src, dst) matrix cell.
 
     ``data``/``offsets``/``lengths`` is the tile-aligned packed layout of
@@ -424,27 +367,22 @@ def transcode_ragged(data, offsets, lengths, *, src: str, dst: str,
     :class:`repro.core.result.RaggedTranscodeResult`: a dense output
     stream in the destination's narrow dtype plus per-document
     ``(offsets, counts, statuses)`` — each document's slice is
-    bit-identical to the single-document fused transcoder's
+    bit-identical to the single-document transcoder's
     ``buffer[:count]`` / ``count`` / ``status``.
 
-    ``strategy="onepass"`` (default) runs the batch as ONE grid launch
-    with the segment scan carried in SMEM (one read + one decode of the
-    packed stream); ``strategy="fused"`` keeps the two-launch
-    count/cumsum/write reference.  Both are bit-identical per document.
+    The batch runs as ONE grid launch with the segment scan carried in
+    SMEM (one read + one decode of the packed stream).
     """
     _check_errors(errors)
     faults.fire(faults.KERNEL_RAGGED)    # chaos-suite hook (no-op in prod)
     codec_s, _codec_d, _f = stages.get_pair(src, dst)
     data, offsets, lengths = _as_packed(data, offsets, lengths,
                                         codec_s.dtype)
-    if strategy not in ("onepass", "fused"):
-        raise ValueError(
-            f"transcode_ragged: unknown strategy {strategy!r} "
-            f"(expected 'onepass' or 'fused')")
-    impl = _ragged_onepass_impl if strategy == "onepass" else _ragged_impl
     with jax.profiler.TraceAnnotation(spans.RAGGED_DISPATCH):
-        return impl(data, offsets, lengths, src, dst, validate,
-                    runtime.resolve_interpret(interpret), errors)
+        return _ragged_onepass_impl(data, offsets, lengths, src, dst,
+                                    validate,
+                                    runtime.resolve_interpret(interpret),
+                                    errors)
 
 
 def scan_ragged(data, offsets, lengths, *, src: str, dst: str,
@@ -462,37 +400,3 @@ def scan_ragged(data, offsets, lengths, *, src: str, dst: str,
                                         codec_s.dtype)
     return _ragged_scan_impl(data, offsets, lengths, src, dst,
                              runtime.resolve_interpret(interpret))
-
-
-# ---------------------------------------------------------------------------
-# Thin per-pair instantiations (the pre-matrix public API).
-
-
-def utf8_to_utf16_ragged(data, offsets, lengths, *, validate: bool = True,
-                         errors: str = "strict", interpret=None,
-                         strategy: str = "onepass"):
-    """Ragged packed-batch UTF-8 -> UTF-16: one launch per batch."""
-    return transcode_ragged(data, offsets, lengths, src="utf8", dst="utf16",
-                            validate=validate, errors=errors,
-                            interpret=interpret, strategy=strategy)
-
-
-def utf8_scan_ragged(data, offsets, lengths, *, interpret=None):
-    """Counting pass only, per document: ``(counts, statuses)``."""
-    return scan_ragged(data, offsets, lengths, src="utf8", dst="utf16",
-                       interpret=interpret)
-
-
-def utf16_to_utf8_ragged(data, offsets, lengths, *, validate: bool = True,
-                         errors: str = "strict", interpret=None,
-                         strategy: str = "onepass"):
-    """Ragged packed-batch UTF-16 -> UTF-8: one launch per batch."""
-    return transcode_ragged(data, offsets, lengths, src="utf16", dst="utf8",
-                            validate=validate, errors=errors,
-                            interpret=interpret, strategy=strategy)
-
-
-def utf16_scan_ragged(data, offsets, lengths, *, interpret=None):
-    """Counting pass only, per document: ``(counts, statuses)``."""
-    return scan_ragged(data, offsets, lengths, src="utf16", dst="utf8",
-                       interpret=interpret)

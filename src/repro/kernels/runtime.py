@@ -9,7 +9,7 @@ static jit argument, so both variants cache independently.
 
 ``tile_with_boundaries`` is the one place the pad-to-VMEM-tiles + zero
 boundary-tile convention lives; every kernel wrapper (ops.py and the
-fused pipeline) shares it.
+transcode kernels) shares it.
 """
 
 from __future__ import annotations

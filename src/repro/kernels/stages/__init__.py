@@ -1,11 +1,11 @@
 """Codec stages: per-format decode/encode tile bodies + the generic
-count/write driver (DESIGN.md §8).
+driver (DESIGN.md §8).
 
 The registry below is the single source of truth for which formats the
-fused/ragged Pallas pipelines speak.  Every (src, dst) pair with
-``src != dst`` is a valid composition of :func:`driver.count_tile` /
-:func:`driver.write_stage`; the classic UTF-8→UTF-16 and UTF-16→UTF-8
-kernels are just two cells of this matrix.
+single-stream and ragged Pallas kernels speak.  Every (src, dst) pair
+with ``src != dst`` is a valid composition of :func:`driver.onepass_tile`
+(transcode) and :func:`driver.count_tile` (scan); the classic
+UTF-8→UTF-16 and UTF-16→UTF-8 kernels are just two cells of this matrix.
 """
 
 from __future__ import annotations
@@ -18,8 +18,7 @@ from repro.kernels.stages import utf8 as s_utf8
 from repro.kernels.stages.driver import (  # noqa: F401  (re-export)
     BLOCK, LANES, ROWS, Codec, ascii_tile_pred, count_decoded,
     count_decoded2, count_tile, decode_once, decode_once2, onepass_tile,
-    stage_decoded, stage_decoded2, stage_units, stage_units2, stage_width,
-    write_stage)
+    stage_decoded, stage_decoded2, stage_units, stage_units2, stage_width)
 
 import jax.numpy as jnp
 

@@ -25,16 +25,15 @@ sides of the code-point intermediate:
                 surrogate folding, for tiles whose every code point fits
                 in 11 bits (DESIGN.md §9 tile-class dispatch).
 
-:func:`count_tile` and :func:`write_stage` compose any pair of codecs
-into the fused pipeline's two passes (DESIGN.md §5/§8); the per-pair tile
-bodies that previously hardwired UTF-8→UTF-16 and UTF-16→UTF-8 are now
-thin instantiations of these two functions.  Both are themselves thin
-compositions of three primitives — :func:`decode_once` (ONE speculative
-decode / maximal-subpart analysis of the tile), :func:`count_decoded`
-(lengths + fused validation over the decoded lanes) and
-:func:`stage_decoded` (in-tile compaction of the decoded lanes) — so the
-single-pass pipeline (:func:`onepass_tile`, DESIGN.md §9) can run count
-AND write off one decode instead of re-decoding the tile per pass.  Each
+:func:`onepass_tile` (transcode) and :func:`count_tile` (scan) compose
+any pair of codecs (DESIGN.md §5/§8/§9); the per-pair tile bodies that
+previously hardwired UTF-8→UTF-16 and UTF-16→UTF-8 are now thin
+instantiations of these functions.  Both are compositions of three
+primitives — :func:`decode_once` (ONE speculative decode /
+maximal-subpart analysis of the tile), :func:`count_decoded` (lengths +
+fused validation over the decoded lanes) and :func:`stage_decoded`
+(in-tile compaction of the decoded lanes) — so the transcode runs count
+AND write off one decode of each tile.  Each
 primitive has a class-specialized twin (:func:`decode_once2` /
 :func:`count_decoded2` / :func:`stage_decoded2`) for the ≤2-byte tile
 class.
@@ -386,17 +385,6 @@ def count_tile(src: Codec, dst: Codec, x, xp, xn, live, gidx, *,
                          validate=validate)
 
 
-def write_stage(src: Codec, dst: Codec, x, xp, xn, instream, *,
-                errors: str):
-    """Decode + in-tile compaction of one tile: the write-pass body.
-
-    ``instream`` is the caller's in-stream mask of ``x``'s shape.
-    """
-    _a, cp, lead = decode_once(src, x, xp, xn, errors=errors,
-                               validate=False)
-    return stage_decoded(src, dst, cp, lead, instream)
-
-
 def ascii_tile_pred(x, xp, lookback: int = 3):
     """Per-tile ASCII fast-path predicate (paper Algorithm 3 at tile
     granularity).
@@ -424,10 +412,9 @@ def onepass_tile(src: Codec, dst: Codec, x, xp, xn, live, gidx, *,
                  per_tile: bool = False):
     """Count + stage one tile off a single decode: the one-pass body.
 
-    Returns ``(total, err_flag, first_err_gidx, stage)`` — the count
-    pass's three per-tile scalars plus the write pass's compact stage
-    window, computed from ONE decode/analysis of the tile (the fused
-    two-pass pipeline decodes every tile twice).  ``x`` may also be a
+    Returns ``(total, err_flag, first_err_gidx, stage)`` — the three
+    per-tile count scalars plus the compact stage window, computed from
+    ONE decode/analysis of the tile.  ``x`` may also be a
     ``(G, ROWS, LANES)`` block of G consecutive tiles, with ``xp`` /
     ``xn`` each tile's own neighbours: ``total`` is then a tuple of the
     G tiles' totals, ``stage`` holds one window per tile, and the error

@@ -51,7 +51,7 @@ def analyze_tile(u, up, un):
     """Unit analysis of one tile given its neighbour tiles.
 
     The body is the shared :func:`repro.core.utf16.analyze_units` (one
-    unit of context each way), so the fused pipeline's unpaired-surrogate
+    unit of context each way), so the kernels' unpaired-surrogate
     location and errors="replace" semantics match the pure-jnp reference
     bit for bit.  Returns the analysis dict (``starts`` / ``valid`` /
     ``cp`` / ``err``).

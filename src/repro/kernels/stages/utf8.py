@@ -52,7 +52,7 @@ def decode_tile(b, bp, bn):
     point, lead-position flag (bool), UTF-16 code units emitted by the
     character (0 at non-leads), and a per-position structural/range error
     map (bool).  Shared between the legacy standalone decode kernel and
-    the generic fused driver.
+    the generic driver.
     """
     b1 = shift_left_flat(b, bn, 1)
     b2 = shift_left_flat(b, bn, 2)
@@ -110,8 +110,8 @@ def analyze_tile(b, bp, bn):
     """Maximal-subpart analysis of one tile given its neighbour tiles.
 
     Same shift convention as :func:`decode_tile`; the body is the shared
-    :func:`repro.core.utf8.analyze_subparts`, so the fused pipeline's
-    error location and errors="replace" semantics are bit-identical to
+    :func:`repro.core.utf8.analyze_subparts`, so the kernels' error
+    location and errors="replace" semantics are bit-identical to
     the pure-jnp block-parallel reference.  Returns the analysis dict
     (``starts`` / ``valid`` / ``cp`` / ``units`` / ``err``).
     """

@@ -30,10 +30,9 @@ prompts and runs ONE ragged launch — a counting scan
 (fused validation + per-document error location) for UTF-8, a ragged
 transcode to UTF-8 through the matrix cell for unit encodings — padded
 to the bucket's geometry, so there is **one compilation per (bucket,
-errors-policy) cell**, held in an LRU-bounded compile cache (the
-``_BATCH_CACHE`` pattern of ``repro.data.pipeline``).  Prefill likewise
-pads to the bucket bound, one compiled cell per bucket instead of one
-per distinct prompt length.  The deadline/retry/shed/fallback machinery
+errors-policy) cell**, held in an LRU-bounded compile cache.  Prefill
+likewise pads to the bucket bound, one compiled cell per bucket instead
+of one per distinct prompt length.  The deadline/retry/shed/fallback machinery
 rides the slot loop: transient launch failures retry with backoff, a
 persistently failing group degrades per-document to the host ``codecs``
 path, expired deadlines free their queue position with a typed
@@ -273,7 +272,7 @@ class Engine:
         self._breakers: Dict[str, _Breaker] = {}
         # "continuous": a freed slot refills immediately, mid-wave.
         # "wave": refill only once ALL slots drain — the wave-batching
-        # reference the table_serve benchmark compares against.
+        # reference.
         self.scheduler = scheduler
         # Observability: how often the robustness paths actually fired.
         #   retries   — transient launch failures retried
@@ -314,8 +313,8 @@ class Engine:
             maxlen=event_limit)
         self._step = 0
         # LRU-bounded compile cache, one jitted cell per (kind, bucket,
-        # errors-policy) — the ``_BATCH_CACHE`` pattern: hit refreshes
-        # recency, insert beyond capacity evicts the coldest executable.
+        # errors-policy): hit refreshes recency, insert beyond capacity
+        # evicts the coldest executable.
         self._cells: "collections.OrderedDict[tuple, object]" = \
             collections.OrderedDict()
         self._cell_limit = compile_cache_size
@@ -998,7 +997,7 @@ class Engine:
             return bytes(byte_vals.astype(np.uint8))
         b = jnp.asarray(byte_vals.astype(np.int32))
         # Pinned to the eager pure-jnp strategy: egress buffers have a
-        # new length per response, and the fused Pallas pipeline would
+        # new length per response, and the onepass Pallas pipeline would
         # recompile per distinct shape.  Wire bytes come from the
         # explicit-LE jnp helpers, never a host ``.view()``.
         if encoding == "utf-16-le":

@@ -31,8 +31,7 @@ Fault points currently wired (grep for ``faults.fire``):
   point               fires in
   ==================  ====================================================
   ``kernel.onepass``  ``onepass_transcode.transcode_onepass``
-  ``kernel.fused``    ``fused_transcode.transcode_fused``
-  ``kernel.scan``     ``fused_transcode.scan_fused``
+  ``kernel.scan``     ``onepass_transcode.scan_onepass``
   ``kernel.ragged``   ``ragged_transcode.transcode_ragged``
   ``kernel.ragged_scan``  ``ragged_transcode.scan_ragged``
   ``stream.chunk``    ``core.stream.transcode_stream_chunk`` (payload:
@@ -70,7 +69,6 @@ import numpy as np
 
 # Fault-point names (import these rather than retyping strings in tests).
 KERNEL_ONEPASS = "kernel.onepass"
-KERNEL_FUSED = "kernel.fused"
 KERNEL_SCAN = "kernel.scan"
 KERNEL_RAGGED = "kernel.ragged"
 KERNEL_RAGGED_SCAN = "kernel.ragged_scan"
@@ -80,9 +78,9 @@ SHARD_LAUNCH = "shard.launch"
 FEED_STAGE = "feed.stage"
 ENGINE_PROBE = "engine.probe"
 
-POINTS = (KERNEL_ONEPASS, KERNEL_FUSED, KERNEL_SCAN, KERNEL_RAGGED,
-          KERNEL_RAGGED_SCAN, STREAM_CHUNK, PIPELINE_BATCH,
-          SHARD_LAUNCH, FEED_STAGE, ENGINE_PROBE)
+POINTS = (KERNEL_ONEPASS, KERNEL_SCAN, KERNEL_RAGGED, KERNEL_RAGGED_SCAN,
+          STREAM_CHUNK, PIPELINE_BATCH, SHARD_LAUNCH, FEED_STAGE,
+          ENGINE_PROBE)
 
 
 class FaultInjected(RuntimeError):

@@ -48,18 +48,18 @@ RAGGED16 = (jnp.asarray(_PK16.data), jnp.asarray(_PK16.offsets),
             jnp.asarray(_PK16.lengths))
 
 # Every deprecated shim with the generic call it must match bit-for-bit
-# (including each shim's HISTORICAL default strategy).
+# (including each shim's default strategy).
 SHIM_CASES = {
     "utf8_to_utf16": ((B8,), lambda: tc.transcode(
         B8, "utf16", src_format="utf8", strategy="blockparallel")),
     "utf8_to_utf32": ((B8,), lambda: tc.transcode(
         B8, "utf32", src_format="utf8", strategy="blockparallel")),
     "utf8_to_latin1": ((B8L,), lambda: tc.transcode(
-        B8L, "latin1", src_format="utf8", strategy="fused")),
+        B8L, "latin1", src_format="utf8", strategy="onepass")),
     "latin1_to_utf8": ((L1,), lambda: tc.transcode(
-        L1, "utf8", src_format="latin1", strategy="fused")),
+        L1, "utf8", src_format="latin1", strategy="onepass")),
     "latin1_to_utf16": ((L1,), lambda: tc.transcode(
-        L1, "utf16", src_format="latin1", strategy="fused")),
+        L1, "utf16", src_format="latin1", strategy="onepass")),
     "utf16_to_utf8": ((U16,), lambda: tc.transcode(
         U16, "utf8", src_format="utf16", strategy="blockparallel")),
     "utf16_to_utf32": ((U16,), lambda: tc.transcode(
@@ -118,6 +118,71 @@ def test_generic_entry_points_keyword_only(fn, kwonly):
     for name in kwonly:
         assert params[name].kind is inspect.Parameter.KEYWORD_ONLY, \
             f"{fn.__name__}(..., {name}=) must be keyword-only"
+
+
+# The strategy names each entry point accepts.  A name outside the
+# registry falls to the unknown-strategy error, which names the
+# registry; every registered name is accepted.
+_DOCS = np.zeros((2, 64), np.uint8)
+_DOCS[0, :5] = np.frombuffer(b"hello", np.uint8)
+_DOCS[1, :3] = np.frombuffer(b"abc", np.uint8)
+_DOC_LENS = np.asarray([5, 3], np.int32)
+
+
+def _entry(entry, strategy):
+    """Call ``entry`` on a small valid input with ``strategy``."""
+    if entry == "transcode":
+        return tc.transcode(B8, "utf16", strategy=strategy)
+    if entry == "scan":
+        return tc.scan(B8, "utf16", strategy=strategy)
+    if entry == "ragged_transcode":
+        return tc.ragged_transcode(*RAGGED8, strategy=strategy)
+    from repro.data import pipeline
+    return pipeline.batch_transcode(_DOCS, _DOC_LENS, strategy=strategy)
+
+
+def _registered():
+    from repro.data import pipeline
+    return ([("transcode", s) for s in tc.STRATEGIES]
+            + [("ragged_transcode", s) for s in tc.RAGGED_STRATEGIES]
+            + [("batch_transcode", s) for s in pipeline.BATCH_STRATEGIES])
+
+
+# Retired with the two-launch kernels ("fused") and the vmap batch,
+# whose per-document names ran a transcoder under jax.vmap.
+RETIRED = [("transcode", "fused"), ("scan", "fused"),
+           ("ragged_transcode", "fused"), ("batch_transcode", "fused"),
+           ("batch_transcode", "vmap"), ("batch_transcode", "onepass"),
+           ("batch_transcode", "blockparallel"),
+           ("batch_transcode", "windowed")]
+
+
+@pytest.mark.parametrize("entry,strategy", RETIRED,
+                         ids=[f"{e}-{s}" for e, s in RETIRED])
+def test_retired_strategy_is_refused(entry, strategy):
+    with pytest.raises(ValueError,
+                       match=r"unknown strategy.*supported") as e:
+        _entry(entry, strategy)
+    assert f"'{strategy}'" not in str(e.value).split("supported")[1]
+
+
+@pytest.mark.parametrize("entry,strategy", _registered(),
+                         ids=[f"{e}-{s}" for e, s in _registered()])
+def test_registered_strategy_is_accepted(entry, strategy):
+    res = _entry(entry, strategy)
+    if entry == "batch_transcode":
+        assert np.array_equal(np.asarray(res.count), [5, 3])
+        assert np.array_equal(np.asarray(res.buffer)[0, :5],
+                              np.frombuffer(b"hello", np.uint8))
+        return
+    if entry == "ragged_transcode":
+        assert np.array_equal(np.asarray(res.counts), [2, 5])
+        assert np.array_equal(np.asarray(res.statuses), [-1, -1])
+        return
+    out, cnt, status = res
+    want = np.frombuffer(S.encode("utf-16-le"), np.uint16)
+    assert int(status) == -1 and int(cnt) == len(want)
+    assert np.array_equal(np.asarray(out)[: len(want)], want)
 
 
 def test_stream_entry_point_keyword_only():

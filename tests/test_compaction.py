@@ -39,8 +39,8 @@ def test_compact_gather_matches_scatter(items):
 
 
 # ---------------------------------------------------------------------------
-# Directed edge cases (satellite of the fused-pipeline PR): empty inputs,
-# degenerate masks, exact-capacity and overflow buffers, tile-scan helpers.
+# Directed edge cases: empty inputs, degenerate masks, exact-capacity and
+# overflow buffers, the tile-scan helper.
 
 
 def test_compact_all_false_mask():
@@ -100,13 +100,6 @@ def test_tile_exclusive_scan_matches_numpy():
     x = np.zeros(256, np.int32)
     excl, tot = compaction.tile_exclusive_scan(jnp.asarray(x), rows=4)
     assert int(tot) == 0 and np.all(np.asarray(excl) == 0)
-
-
-def test_tile_base_offsets_matches_numpy():
-    totals = jnp.asarray([3, 0, 7, 1], jnp.int32)
-    base, total = compaction.tile_base_offsets(totals)
-    assert np.array_equal(np.asarray(base), [0, 3, 3, 10])
-    assert int(total) == 11
 
 
 @settings(**SETTINGS)

@@ -3,9 +3,9 @@
 Seeded random + mutated corpora are checked **byte-exactly** against
 CPython's ``codecs`` machinery across every
 (direction x strategy x errors) cell — the single-launch ``onepass``
-strategy (DESIGN.md §9) rides every sweep next to ``fused`` — including
-the ragged packed-batch path (both launch strategies) with per-document
-statuses:
+kernel (DESIGN.md §9) rides every sweep next to the pure-jnp
+``blockparallel`` reference — including the ragged packed-batch path
+(one device and sharded) with per-document statuses:
 
   * valid streams: ``buffer[:count]`` must equal the CPython transcode
     bit for bit, ``status`` must be -1;
@@ -41,7 +41,8 @@ import jax.numpy as jnp
 from repro.core import packing
 from repro.core import transcode as tc
 from repro.data import synthetic
-from repro.kernels import fused_transcode as ft
+from repro.kernels import onepass_transcode as op
+from repro.kernels import ragged_transcode as rt
 from repro.kernels import runtime
 
 SEED = int(os.environ.get("REPRO_FUZZ_SEED", "20260801"))
@@ -198,7 +199,7 @@ def boundary_documents16():
 
 def _check8_strict(buf, n, strategy):
     want, want_pos = _py8(bytes(buf[:n]))
-    x = jnp.asarray(buf if strategy in ("fused", "onepass")
+    x = jnp.asarray(buf if strategy == "onepass"
                     else buf.astype(np.int32))
     out, cnt, status = tc.transcode_utf8_to_utf16(x, n, strategy=strategy)
     assert int(status) == want_pos
@@ -219,7 +220,7 @@ def _check8_strict(buf, n, strategy):
 def _check8_replace(buf, n, strategy):
     want = _py8_replace(bytes(buf[:n]))
     _, want_pos = _py8(bytes(buf[:n]))
-    x = jnp.asarray(buf if strategy in ("fused", "onepass")
+    x = jnp.asarray(buf if strategy == "onepass"
                     else buf.astype(np.int32))
     out, cnt, status = tc.transcode_utf8_to_utf16(x, n, strategy=strategy,
                                                   errors="replace")
@@ -228,7 +229,7 @@ def _check8_replace(buf, n, strategy):
     assert np.array_equal(np.asarray(out)[: int(cnt)], want)
 
 
-@pytest.mark.parametrize("strategy", ["onepass", "fused", "blockparallel"])
+@pytest.mark.parametrize("strategy", ["onepass", "blockparallel"])
 def test_differential_utf8_to_utf16(strategy):
     rng = np.random.default_rng(SEED)
     for trial in range(20):
@@ -247,7 +248,7 @@ def test_differential_utf8_to_utf16_windowed():
 
 def _check16_strict(buf, n, strategy):
     want, want_pos = _py16(buf[:n])
-    x = jnp.asarray(buf if strategy in ("fused", "onepass")
+    x = jnp.asarray(buf if strategy == "onepass"
                     else buf.astype(np.int32))
     out, cnt, status = tc.transcode_utf16_to_utf8(x, n, strategy=strategy)
     assert int(status) == want_pos
@@ -264,7 +265,7 @@ def _check16_strict(buf, n, strategy):
 def _check16_replace(buf, n, strategy):
     want = _py16_replace(buf[:n])
     _, want_pos = _py16(buf[:n])
-    x = jnp.asarray(buf if strategy in ("fused", "onepass")
+    x = jnp.asarray(buf if strategy == "onepass"
                     else buf.astype(np.int32))
     out, cnt, status = tc.transcode_utf16_to_utf8(x, n, strategy=strategy,
                                                   errors="replace")
@@ -273,7 +274,7 @@ def _check16_replace(buf, n, strategy):
     assert np.array_equal(np.asarray(out)[: int(cnt)], want)
 
 
-@pytest.mark.parametrize("strategy", ["onepass", "fused", "blockparallel"])
+@pytest.mark.parametrize("strategy", ["onepass", "blockparallel"])
 def test_differential_utf16_to_utf8(strategy):
     rng = np.random.default_rng(SEED + 2)
     for trial in range(16):
@@ -311,14 +312,15 @@ def _check_ragged8(docs, errors, strategy="onepass"):
             want, _ = _py8(raw)
             assert int(res.counts[d]) == len(want), d
             assert np.array_equal(got, want), d
-        # Acceptance: bit-identical to the per-document fused transcoder
+        # Acceptance: bit-identical to the single-document transcoder
         # (buffer, count, status) on the fuzz corpus.  Capacity = the
         # doc's tile span, so single-doc compilations are shared.
         span = max(int(pk.offsets[d + 1] - pk.offsets[d]), 1)
         buf = np.zeros(span, np.uint8)
         buf[: len(raw)] = np.frombuffer(raw, np.uint8)
-        single = ft.utf8_to_utf16_fused(jnp.asarray(buf), len(raw),
-                                        errors=errors)
+        single = op.transcode_onepass(jnp.asarray(buf), len(raw),
+                                      src="utf8", dst="utf16",
+                                      errors=errors)
         assert int(res.counts[d]) == int(single.count), d
         assert int(res.statuses[d]) == int(single.status), d
         k = min(int(single.count), span)
@@ -346,15 +348,16 @@ def _check_ragged16(docs, errors, strategy="onepass"):
         span = max(int(pk.offsets[d + 1] - pk.offsets[d]), 1)
         buf = np.zeros(span, np.uint16)
         buf[: len(u)] = u
-        single = ft.utf16_to_utf8_fused(jnp.asarray(buf), len(u),
-                                        errors=errors)
+        single = op.transcode_onepass(jnp.asarray(buf), len(u),
+                                      src="utf16", dst="utf8",
+                                      errors=errors)
         assert int(res.counts[d]) == int(single.count), d
         assert int(res.statuses[d]) == int(single.status), d
         k = min(int(single.count), 3 * span)
         assert np.array_equal(got[:k], np.asarray(single.buffer)[:k]), d
 
 
-@pytest.mark.parametrize("strategy", ["onepass", "fused"])
+@pytest.mark.parametrize("strategy", tc.RAGGED_STRATEGIES)
 @pytest.mark.parametrize("errors", ["strict", "replace"])
 def test_differential_ragged_utf8_fuzz(errors, strategy):
     rng = np.random.default_rng(SEED + 4)
@@ -368,7 +371,7 @@ def test_differential_ragged_utf8_fuzz(errors, strategy):
         _check_ragged8(docs, errors, strategy)
 
 
-@pytest.mark.parametrize("strategy", ["onepass", "fused"])
+@pytest.mark.parametrize("strategy", tc.RAGGED_STRATEGIES)
 @pytest.mark.parametrize("errors", ["strict", "replace"])
 def test_differential_ragged_utf16_fuzz(errors, strategy):
     rng = np.random.default_rng(SEED + 5)
@@ -381,13 +384,13 @@ def test_differential_ragged_utf16_fuzz(errors, strategy):
         _check_ragged16(docs, errors, strategy)
 
 
-@pytest.mark.parametrize("strategy", ["onepass", "fused"])
+@pytest.mark.parametrize("strategy", tc.RAGGED_STRATEGIES)
 @pytest.mark.parametrize("errors", ["strict", "replace"])
 def test_differential_ragged_boundary_adversarial_utf8(errors, strategy):
     _check_ragged8(boundary_documents8(), errors, strategy)
 
 
-@pytest.mark.parametrize("strategy", ["onepass", "fused"])
+@pytest.mark.parametrize("strategy", tc.RAGGED_STRATEGIES)
 @pytest.mark.parametrize("errors", ["strict", "replace"])
 def test_differential_ragged_boundary_adversarial_utf16(errors, strategy):
     _check_ragged16(boundary_documents16(), errors, strategy)
@@ -402,7 +405,7 @@ def test_boundary_probes_also_hit_single_doc_strategies():
             continue
         buf = np.zeros(CAP8, np.uint8)
         buf[:n] = doc
-        for strategy in ("onepass", "fused", "blockparallel"):
+        for strategy in ("onepass", "blockparallel"):
             _check8_strict(buf, n, strategy)
             _check8_replace(buf, n, strategy)
 
@@ -460,7 +463,7 @@ CAPM = 1280   # fixed matrix-cell capacity: one compilation per cell
 def _matrix_transcode(src, dst, arr, strategy, errors):
     buf = np.zeros(max(CAPM, len(arr)), _WIRE_DT[src])
     buf[: len(arr)] = arr
-    x = jnp.asarray(buf) if strategy in ("fused", "onepass") \
+    x = jnp.asarray(buf) if strategy == "onepass" \
         else jnp.asarray(buf.astype(np.int64).astype(np.int32))
     return tc.transcode(x, dst, src_format=src, n_valid=len(arr),
                         strategy=strategy, errors=errors)
@@ -531,7 +534,7 @@ def _matrix_case(src, rng, trial, cap):
 
 
 @pytest.mark.parametrize("src,dst", MATRIX_NEW_PAIRS)
-@pytest.mark.parametrize("strategy", ["onepass", "fused", "blockparallel"])
+@pytest.mark.parametrize("strategy", ["onepass", "blockparallel"])
 def test_differential_matrix_cells(src, dst, strategy):
     rng = np.random.default_rng(SEED + 8)
     for trial in range(8):
@@ -549,32 +552,32 @@ def test_differential_matrix_boundary_adversarial():
             buf = np.full(2048, 0x41, np.uint8)
             buf[pos: pos + len(probe)] = np.frombuffer(probe, np.uint8)
             for dst in ("utf32", "latin1"):
-                _check_matrix_cell("utf8", dst, buf, "fused")
+                _check_matrix_cell("utf8", dst, buf, "onepass")
     # utf32 source: a bad scalar at the tile boundary
     for bad in (0xD800, 0x110000):
         cps = np.full(1100, 0x41, np.uint32)
         cps[1023] = bad
         for dst in ("utf8", "utf16"):
-            _check_matrix_cell("utf32", dst, cps, "fused")
+            _check_matrix_cell("utf32", dst, cps, "onepass")
     # latin1 source: high bytes straddling the boundary widen to 2-byte
     # UTF-8 sequences across it
     b = np.full(1100, 0x41, np.uint8)
     b[1020:1028] = 0xE9
-    _check_matrix_cell("latin1", "utf8", b, "fused")
+    _check_matrix_cell("latin1", "utf8", b, "onepass")
     # utf8 -> latin1: an unencodable (but valid UTF-8) char at the
     # boundary must locate at its lead byte
     s = "A" * 1022 + "中" + "B" * 64
     arr = np.frombuffer(s.encode("utf-8"), np.uint8)
-    _check_matrix_cell("utf8", "latin1", arr, "fused")
+    _check_matrix_cell("utf8", "latin1", arr, "onepass")
 
 
 @pytest.mark.parametrize("src,dst", [("utf8", "utf32"), ("latin1", "utf8"),
                                      ("utf8", "latin1")])
-@pytest.mark.parametrize("strategy", ["onepass", "fused"])
+@pytest.mark.parametrize("strategy", tc.RAGGED_STRATEGIES)
 @pytest.mark.parametrize("errors", ["strict", "replace"])
 def test_differential_matrix_ragged(src, dst, errors, strategy):
     """Ragged matrix cells: per-document parity with the single-document
-    fused transcoder and with the CPython oracle."""
+    transcoder and with the CPython oracle."""
     rng = np.random.default_rng(SEED + 9)
     docs = [_matrix_case(src, rng, t, cap=1200) for t in range(5)]
     docs.insert(1, np.zeros(0, _WIRE_DT[src]))           # empty mixed in
@@ -592,37 +595,12 @@ def test_differential_matrix_ragged(src, dst, errors, strategy):
         span = max(int(pk.offsets[d + 1] - pk.offsets[d]), 1)
         buf = np.zeros(span, _WIRE_DT[src])
         buf[: len(doc)] = doc
-        single = ft.transcode_fused(jnp.asarray(buf), len(doc), src=src,
-                                    dst=dst, errors=errors)
+        single = op.transcode_onepass(jnp.asarray(buf), len(doc), src=src,
+                                      dst=dst, errors=errors)
         assert int(res.counts[d]) == int(single.count), d
         assert int(res.statuses[d]) == int(single.status), d
         k = min(int(single.count), factor * span)
         assert np.array_equal(got[:k], np.asarray(single.buffer)[:k]), d
-
-
-@pytest.mark.parametrize("src,dst", MATRIX_NEW_PAIRS)
-def test_parity_matrix_interpret_vs_compiled(src, dst):
-    """Matrix cells: interpreter kernels vs the XLA-compiled
-    blockparallel reference (and Mosaic vs interpreter on TPU)."""
-    rng = np.random.default_rng(SEED + 10)
-    for trial in range(4):
-        arr = _matrix_case(src, rng, trial, cap=1280)
-        interp = ft.transcode_fused(jnp.asarray(arr), len(arr), src=src,
-                                    dst=dst, interpret=True)
-        ref = _matrix_transcode(src, dst, arr, "blockparallel", "strict")
-        assert int(interp.count) == int(ref.count), (src, dst, trial)
-        assert int(interp.status) == int(ref.status), (src, dst, trial)
-        k = int(interp.count)
-        assert np.array_equal(
-            np.asarray(interp.buffer)[:k].astype(np.int64),
-            np.asarray(ref.buffer)[:k].astype(np.int64)), (src, dst, trial)
-        if _on_tpu():   # pragma: no cover - TPU-only branch
-            comp = ft.transcode_fused(jnp.asarray(arr), len(arr), src=src,
-                                      dst=dst, interpret=False)
-            assert int(comp.count) == int(interp.count)
-            assert int(comp.status) == int(interp.status)
-            assert np.array_equal(np.asarray(comp.buffer),
-                                  np.asarray(interp.buffer))
 
 
 # ---------------------------------------------------------------------------
@@ -642,77 +620,42 @@ def test_parity_resolution_matches_backend():
     assert runtime.resolve_interpret(None) == (not _on_tpu())
 
 
-@pytest.mark.parametrize("errors", ["strict", "replace"])
-def test_parity_utf8_interpret_vs_compiled(errors):
-    rng = np.random.default_rng(SEED + 6)
-    for trial in range(8):
+def _parity_case(direction, rng, trial):
+    """``(buffer, n, src, dst, capacity)`` of one parity trial."""
+    if direction == "utf8-utf16":
         buf, n = _utf8_case(rng, trial)
-        interp = ft.utf8_to_utf16_fused(jnp.asarray(buf), n, errors=errors,
-                                        interpret=True)
-        # Compiled reference: the pure-jnp strategy under jit.
-        ref = tc.utf8_to_utf16(jnp.asarray(buf.astype(np.int32)), n,
-                               errors=errors)
-        assert int(interp.count) == int(ref.count), trial
-        assert int(interp.status) == int(ref.status), trial
-        k = min(int(interp.count), CAP8)
-        assert np.array_equal(np.asarray(interp.buffer)[:k],
-                              np.asarray(ref.buffer)[:k]), trial
-        if _on_tpu():   # pragma: no cover - TPU-only branch
-            comp = ft.utf8_to_utf16_fused(jnp.asarray(buf), n,
-                                          errors=errors, interpret=False)
-            assert int(comp.count) == int(interp.count)
-            assert int(comp.status) == int(interp.status)
-            assert np.array_equal(np.asarray(comp.buffer),
-                                  np.asarray(interp.buffer))
+        return buf, n, "utf8", "utf16", CAP8
+    buf, n = _utf16_case(rng, trial)
+    return buf, n, "utf16", "utf8", 3 * CAP16
 
 
 @pytest.mark.parametrize("errors", ["strict", "replace"])
-def test_parity_utf16_interpret_vs_compiled(errors):
-    rng = np.random.default_rng(SEED + 7)
-    for trial in range(6):
-        buf, n = _utf16_case(rng, trial)
-        interp = ft.utf16_to_utf8_fused(jnp.asarray(buf), n, errors=errors,
-                                        interpret=True)
-        ref = tc.utf16_to_utf8(jnp.asarray(buf.astype(np.int32)), n,
-                               errors=errors)
-        assert int(interp.count) == int(ref.count), trial
-        assert int(interp.status) == int(ref.status), trial
-        k = min(int(interp.count), 3 * CAP16)
-        assert np.array_equal(np.asarray(interp.buffer)[:k],
-                              np.asarray(ref.buffer)[:k]), trial
-        if _on_tpu():   # pragma: no cover - TPU-only branch
-            comp = ft.utf16_to_utf8_fused(jnp.asarray(buf), n,
-                                          errors=errors, interpret=False)
-            assert int(comp.count) == int(interp.count)
-            assert int(comp.status) == int(interp.status)
-            assert np.array_equal(np.asarray(comp.buffer),
-                                  np.asarray(interp.buffer))
-
-
-@pytest.mark.parametrize("errors", ["strict", "replace"])
-def test_parity_onepass_interpret_vs_compiled(errors):
+@pytest.mark.parametrize("direction", ["utf8-utf16", "utf16-utf8"])
+def test_parity_onepass_interpret_vs_compiled(direction, errors):
     """One-pass kernels (single launch, SMEM carry): interpreter vs the
     XLA-compiled blockparallel reference on CPU; on a TPU backend the
     same test additionally pins the Mosaic-compiled kernel — whose
     sequential-grid carry is the §9 correctness assumption — to the
     interpreter."""
-    from repro.kernels import onepass_transcode as opk
     rng = np.random.default_rng(SEED + 11)
     for trial in range(8):
-        buf, n = _utf8_case(rng, trial)
-        interp = opk.utf8_to_utf16_onepass(jnp.asarray(buf), n,
-                                           errors=errors, interpret=True)
-        ref = tc.utf8_to_utf16(jnp.asarray(buf.astype(np.int32)), n,
-                               errors=errors)
+        buf, n, src, dst, cap = _parity_case(direction, rng, trial)
+        interp = op.transcode_onepass(jnp.asarray(buf), n, src=src,
+                                      dst=dst, errors=errors,
+                                      interpret=True)
+        # Compiled reference: the pure-jnp strategy under jit.
+        ref = tc.transcode(jnp.asarray(buf.astype(np.int32)), dst,
+                           src_format=src, n_valid=n,
+                           strategy="blockparallel", errors=errors)
         assert int(interp.count) == int(ref.count), trial
         assert int(interp.status) == int(ref.status), trial
-        k = min(int(interp.count), CAP8)
+        k = min(int(interp.count), cap)
         assert np.array_equal(np.asarray(interp.buffer)[:k],
                               np.asarray(ref.buffer)[:k]), trial
         if _on_tpu():   # pragma: no cover - TPU-only branch
-            comp = opk.utf8_to_utf16_onepass(jnp.asarray(buf), n,
-                                             errors=errors,
-                                             interpret=False)
+            comp = op.transcode_onepass(jnp.asarray(buf), n, src=src,
+                                        dst=dst, errors=errors,
+                                        interpret=False)
             assert int(comp.count) == int(interp.count)
             assert int(comp.status) == int(interp.status)
             assert np.array_equal(np.asarray(comp.buffer),
@@ -724,12 +667,11 @@ def test_parity_onepass_matrix_interpret_vs_compiled(src, dst):
     """Matrix cells through the one-pass kernel: interpreter vs the
     compiled blockparallel reference (and Mosaic vs interpreter on
     TPU)."""
-    from repro.kernels import onepass_transcode as opk
     rng = np.random.default_rng(SEED + 12)
-    for trial in range(3):
+    for trial in range(4):
         arr = _matrix_case(src, rng, trial, cap=1280)
-        interp = opk.transcode_onepass(jnp.asarray(arr), len(arr), src=src,
-                                       dst=dst, interpret=True)
+        interp = op.transcode_onepass(jnp.asarray(arr), len(arr), src=src,
+                                      dst=dst, interpret=True)
         ref = _matrix_transcode(src, dst, arr, "blockparallel", "strict")
         assert int(interp.count) == int(ref.count), (src, dst, trial)
         assert int(interp.status) == int(ref.status), (src, dst, trial)
@@ -738,58 +680,37 @@ def test_parity_onepass_matrix_interpret_vs_compiled(src, dst):
             np.asarray(interp.buffer)[:k].astype(np.int64),
             np.asarray(ref.buffer)[:k].astype(np.int64)), (src, dst, trial)
         if _on_tpu():   # pragma: no cover - TPU-only branch
-            comp = opk.transcode_onepass(jnp.asarray(arr), len(arr),
-                                         src=src, dst=dst, interpret=False)
+            comp = op.transcode_onepass(jnp.asarray(arr), len(arr),
+                                        src=src, dst=dst, interpret=False)
             assert int(comp.count) == int(interp.count)
             assert int(comp.status) == int(interp.status)
             assert np.array_equal(np.asarray(comp.buffer),
                                   np.asarray(interp.buffer))
 
 
-def test_parity_ragged_onepass_interpret_vs_compiled():
+@pytest.mark.parametrize("src,dst,docs", [
+    ("utf8", "utf16", boundary_documents8),
+    ("utf16", "utf8", boundary_documents16)], ids=["utf8", "utf16"])
+def test_parity_ragged_onepass_interpret_vs_compiled(src, dst, docs):
     """Ragged one-pass launch: interpreter vs the per-document compiled
     reference (and Mosaic vs interpreter on TPU)."""
-    from repro.kernels import ragged_transcode as rt
-    docs = boundary_documents8()
-    pk = packing.pack_documents(docs, dtype=np.uint8)
-    interp = rt.utf8_to_utf16_ragged(pk.data, pk.offsets, pk.lengths,
-                                     interpret=True, strategy="onepass")
+    docs = docs()
+    dtype = _WIRE_DT[src]
+    pk = packing.pack_documents(docs, dtype=dtype)
+    interp = rt.transcode_ragged(pk.data, pk.offsets, pk.lengths, src=src,
+                                 dst=dst, interpret=True)
     for d, doc in enumerate(docs):
         n = len(doc)
-        buf = np.zeros(max(n, 1), np.uint8)
+        buf = np.zeros(max(n, 1), dtype)
         buf[:n] = doc
-        ref = tc.utf8_to_utf16(jnp.asarray(buf.astype(np.int32)), n)
+        ref = tc.transcode(jnp.asarray(buf.astype(np.int32)), dst,
+                           src_format=src, n_valid=n,
+                           strategy="blockparallel")
         assert int(interp.counts[d]) == int(ref.count), d
         assert int(interp.statuses[d]) == int(ref.status), d
     if _on_tpu():   # pragma: no cover - TPU-only branch
-        comp = rt.utf8_to_utf16_ragged(pk.data, pk.offsets, pk.lengths,
-                                       interpret=False, strategy="onepass")
-        assert np.array_equal(np.asarray(comp.buffer),
-                              np.asarray(interp.buffer))
-        assert np.array_equal(np.asarray(comp.counts),
-                              np.asarray(interp.counts))
-        assert np.array_equal(np.asarray(comp.statuses),
-                              np.asarray(interp.statuses))
-
-
-def test_parity_ragged_interpret_vs_compiled():
-    """Ragged packed path: interpreter kernels vs the per-document
-    compiled reference, per document (and Mosaic vs interpreter on TPU)."""
-    from repro.kernels import ragged_transcode as rt
-    docs = boundary_documents8()
-    pk = packing.pack_documents(docs, dtype=np.uint8)
-    interp = rt.utf8_to_utf16_ragged(pk.data, pk.offsets, pk.lengths,
-                                     interpret=True)
-    for d, doc in enumerate(docs):
-        n = len(doc)
-        buf = np.zeros(max(n, 1), np.uint8)
-        buf[:n] = doc
-        ref = tc.utf8_to_utf16(jnp.asarray(buf.astype(np.int32)), n)
-        assert int(interp.counts[d]) == int(ref.count), d
-        assert int(interp.statuses[d]) == int(ref.status), d
-    if _on_tpu():   # pragma: no cover - TPU-only branch
-        comp = rt.utf8_to_utf16_ragged(pk.data, pk.offsets, pk.lengths,
-                                       interpret=False)
+        comp = rt.transcode_ragged(pk.data, pk.offsets, pk.lengths,
+                                   src=src, dst=dst, interpret=False)
         assert np.array_equal(np.asarray(comp.buffer),
                               np.asarray(interp.buffer))
         assert np.array_equal(np.asarray(comp.counts),

@@ -366,16 +366,9 @@ def _x_onepass():
                          src="utf8", dst="utf16")
 
 
-def _x_fused():
-    from repro.kernels import fused_transcode as ft
-    ft.transcode_fused(jnp.asarray(np.frombuffer(b"hello", np.uint8)),
-                       src="utf8", dst="utf16")
-
-
 def _x_scan():
-    from repro.kernels import fused_transcode as ft
-    ft.scan_fused(jnp.asarray(np.frombuffer(b"hello", np.uint8)),
-                  src="utf8", dst="utf16")
+    op.scan_onepass(jnp.asarray(np.frombuffer(b"hello", np.uint8)),
+                    src="utf8", dst="utf16")
 
 
 def _x_ragged():
@@ -439,7 +432,6 @@ def _x_engine_probe():
 
 _EXERCISERS = {
     faults.KERNEL_ONEPASS: _x_onepass,
-    faults.KERNEL_FUSED: _x_fused,
     faults.KERNEL_SCAN: _x_scan,
     faults.KERNEL_RAGGED: _x_ragged,
     faults.KERNEL_RAGGED_SCAN: _x_ragged_scan,

@@ -16,7 +16,7 @@ from _jaxpr import iter_eqns, pallas_eqns
 
 from repro.core import transcode as tc
 from repro.data import pipeline, synthetic
-from repro.kernels import fused_transcode as ft
+from repro.kernels import onepass_transcode as op
 from repro.kernels import stages
 from repro.kernels.stages import driver as sdrv
 
@@ -68,11 +68,12 @@ def test_matrix_stage_widths_are_derived():
 def test_matrix_stage_overflow_regression_surrogate_flood():
     """A tile of 0xDBFF units folds EVERY lane to a supplementary pair
     code point (4 speculative UTF-8 bytes each, 4*BLOCK per tile) — the
-    old 3*BLOCK+1 stage silently dropped the tail and the fused output
+    old 3*BLOCK+1 stage silently dropped the tail and the kernel output
     diverged from blockparallel.  Pin the fix."""
     for unit in (0xDBFF, 0xDBFF):
         u = np.full(2048, unit, np.uint16)
-        f = ft.utf16_to_utf8_fused(jnp.asarray(u), len(u))
+        f = op.transcode_onepass(jnp.asarray(u), len(u), src="utf16",
+                                 dst="utf8")
         b = tc.utf16_to_utf8(jnp.asarray(u.astype(np.int32)), len(u))
         assert int(f.count) == int(b.count)
         k = int(f.count)
@@ -80,7 +81,8 @@ def test_matrix_stage_overflow_regression_surrogate_flood():
                               np.asarray(b.buffer)[:k].astype(np.uint8))
     # alternating DBFF/FFFF: 4-byte and 3-byte speculative lanes mixed
     u = np.tile(np.array([0xDBFF, 0xFFFF], np.uint16), 1024)
-    f = ft.utf16_to_utf8_fused(jnp.asarray(u), len(u))
+    f = op.transcode_onepass(jnp.asarray(u), len(u), src="utf16",
+                             dst="utf8")
     b = tc.utf16_to_utf8(jnp.asarray(u.astype(np.int32)), len(u))
     assert int(f.count) == int(b.count)
     k = int(f.count)
@@ -94,50 +96,58 @@ def test_matrix_stage_overflow_regression_surrogate_flood():
 
 def test_matrix_cells_share_one_generic_driver(monkeypatch):
     """Tracing ANY matrix cell must invoke the stages package's single
-    ``count_tile``/``write_stage`` driver — no per-pair kernel bodies.
-    UTF-8→UTF-16 (the classic cell) and UTF-8→UTF-32 / latin1→utf8 (new
-    cells) are counted through the same monkeypatched entry points."""
-    calls = {"count": [], "write": []}
-    real_count, real_write = sdrv.count_tile, sdrv.write_stage
+    ``onepass_tile`` (transcode) / ``count_tile`` (scan) driver — no
+    per-pair kernel bodies.  UTF-8→UTF-16 (the classic cell) and
+    UTF-8→UTF-32 / latin1→utf8 (new cells) are counted through the same
+    monkeypatched entry points."""
+    calls = {"count": [], "transcode": []}
+    real_count, real_onepass = sdrv.count_tile, sdrv.onepass_tile
 
     def spy_count(src, dst, *a, **k):
         calls["count"].append((src.name, dst.name))
         return real_count(src, dst, *a, **k)
 
-    def spy_write(src, dst, *a, **k):
-        calls["write"].append((src.name, dst.name))
-        return real_write(src, dst, *a, **k)
+    def spy_onepass(src, dst, *a, **k):
+        calls["transcode"].append((src.name, dst.name))
+        return real_onepass(src, dst, *a, **k)
 
     monkeypatch.setattr(sdrv, "count_tile", spy_count)
-    monkeypatch.setattr(sdrv, "write_stage", spy_write)
+    monkeypatch.setattr(sdrv, "onepass_tile", spy_onepass)
+    # A program traced earlier in this process would not run the spies.
+    jax.clear_caches()
 
     cap = 2048
     for src, dst, dt in (("utf8", "utf16", jnp.uint8),
                          ("utf8", "utf32", jnp.uint8),
                          ("latin1", "utf8", jnp.uint8)):
         jax.make_jaxpr(
-            lambda x, s=src, d=dst: ft.transcode_fused(
+            lambda x, s=src, d=dst: op.transcode_onepass(
                 x, cap - 5, src=s, dst=d, ascii_fastpath=False)
         )(jnp.zeros((cap,), dt))
+        jax.make_jaxpr(
+            lambda x, s=src, d=dst: op.scan_onepass(x, cap - 5, src=s,
+                                                    dst=d)
+        )(jnp.zeros((cap,), dt))
         assert (src, dst) in calls["count"], (src, dst, calls["count"])
-        assert (src, dst) in calls["write"], (src, dst, calls["write"])
+        assert (src, dst) in calls["transcode"], \
+            (src, dst, calls["transcode"])
 
 
 @pytest.mark.parametrize("src,dst,dt", [("utf8", "utf16", jnp.uint8),
                                         ("utf8", "utf32", jnp.uint8),
                                         ("utf32", "utf8", jnp.uint32),
                                         ("latin1", "utf8", jnp.uint8)])
-def test_matrix_jaxpr_two_passes_narrow_io(src, dst, dt):
-    """Every fused matrix cell is the same two-launch shape (count pass +
-    write pass, nothing else), with narrow-dtype large operands."""
+def test_matrix_jaxpr_one_launch_narrow_io(src, dst, dt):
+    """Every matrix cell is the same one-launch shape (count and write
+    off one decode, nothing else), with narrow-dtype large operands."""
     cap = 2048
     itemsize = stages.get_codec(src).itemsize
     jaxpr = jax.make_jaxpr(
-        lambda x: ft.transcode_fused(x, cap - 5, src=src, dst=dst,
-                                     ascii_fastpath=False)
+        lambda x: op.transcode_onepass(x, cap - 5, src=src, dst=dst,
+                                       ascii_fastpath=False)
     )(jnp.zeros((cap,), dt)).jaxpr
     kernels = pallas_eqns(jaxpr)
-    assert len(kernels) == 2, (src, dst, len(kernels))
+    assert len(kernels) == 1, (src, dst, len(kernels))
     for eqn in kernels:
         for v in eqn.invars:
             if v.aval.size >= cap:
@@ -153,7 +163,7 @@ def test_matrix_jaxpr_two_passes_narrow_io(src, dst, dt):
 def test_matrix_latin1_roundtrip_and_substitution():
     t = "café ÿ þ £"
     l1 = np.frombuffer(t.encode("latin-1"), np.uint8)
-    for strat in ("fused", "blockparallel"):
+    for strat in ("onepass", "blockparallel"):
         r = tc.latin1_to_utf8(jnp.asarray(l1), len(l1), strategy=strat)
         assert int(r.status) == -1
         assert bytes(np.asarray(r.buffer)[: int(r.count)].astype(np.uint8)) \
@@ -167,7 +177,7 @@ def test_matrix_latin1_roundtrip_and_substitution():
     s = "ab 中 é"
     b = np.frombuffer(s.encode("utf-8"), np.uint8)
     want_pos = len("ab ".encode("utf-8"))
-    for strat in ("fused", "blockparallel"):
+    for strat in ("onepass", "blockparallel"):
         r = tc.utf8_to_latin1(jnp.asarray(b), len(b), strategy=strat)
         assert int(r.status) == want_pos, strat
         r = tc.utf8_to_latin1(jnp.asarray(b), len(b), errors="replace",
@@ -179,7 +189,7 @@ def test_matrix_latin1_roundtrip_and_substitution():
 
 def test_matrix_utf32_strict_substitutes_but_locates():
     cps = np.array([0x41, 0xD800, 0x1F389, 0x110000, 0x42], np.uint32)
-    for strat in ("fused", "blockparallel"):
+    for strat in ("onepass", "blockparallel"):
         out, cnt, status = tc.utf32_to_utf8(jnp.asarray(cps), len(cps),
                                             strategy=strat)
         assert int(status) == 1, strat
@@ -196,7 +206,7 @@ def test_matrix_ascii_fastpath_rejects_wrapped_negative_utf32():
     strategies locate it and substitute U+FFFD (review regression)."""
     cps = np.array([0x41, 0xFFFFFFFF, 0x42], np.uint32)
     want = "A�B".encode("utf-8")
-    for strat in ("fused", "blockparallel"):
+    for strat in ("onepass", "blockparallel"):
         out, cnt, status = tc.utf32_to_utf8(jnp.asarray(cps), len(cps),
                                             strategy=strat)
         assert int(status) == 1, strat
@@ -208,7 +218,7 @@ def test_matrix_ascii_fastpath_rejects_wrapped_negative_utf32():
 def test_matrix_scan_counts_destination_units():
     s = "naïve 中文 🎉"
     b = np.frombuffer(s.encode("utf-8"), np.uint8)
-    for strat in ("fused", "blockparallel"):
+    for strat in ("onepass", "blockparallel"):
         cnt, status = tc.scan(jnp.asarray(b), "utf32", src_format="utf8",
                               n_valid=len(b), strategy=strat)
         assert int(status) == -1
@@ -216,6 +226,35 @@ def test_matrix_scan_counts_destination_units():
         cnt16, _ = tc.scan(jnp.asarray(b), "utf16", src_format="utf8",
                            n_valid=len(b), strategy=strat)
         assert int(cnt16) == len(s.encode("utf-16-le")) // 2, strat
+
+
+_GEN_HI = {1: 256, 2: 1 << 16, 4: 0x110000}
+
+
+@pytest.mark.parametrize("src,dst", tc.PAIRS)
+def test_matrix_scan_matches_blockparallel_all_cells(src, dst):
+    """The counting kernel over every matrix cell: ``(count, status)``
+    equal the pure-jnp count and the strict transcode's, on garbage
+    across the source's whole range with ``n_valid`` inside the last
+    tile."""
+    rng = np.random.default_rng(20261021)
+    codec = stages.get_codec(src)
+    cap = 2 * stages.BLOCK
+    for trial in range(2):
+        arr = rng.integers(0, _GEN_HI[codec.itemsize], cap)
+        if trial:       # mostly ASCII, a few wide values
+            arr = np.where(rng.random(cap) < 0.9, arr % 0x80, arr)
+        arr = jnp.asarray(arr.astype(codec.dtype))
+        n = int(rng.integers(stages.BLOCK, cap))
+        cnt, status = op.scan_onepass(arr, n, src=src, dst=dst)
+        ref = tc.scan(arr, dst, src_format=src, n_valid=n,
+                      strategy="blockparallel")
+        assert (int(cnt), int(status)) == (int(ref[0]), int(ref[1])), \
+            (src, dst, trial)
+        res = op.transcode_onepass(arr, n, src=src, dst=dst)
+        assert (int(cnt), int(status)) == (int(res.count),
+                                           int(res.status)), \
+            (src, dst, trial)
 
 
 # ---------------------------------------------------------------------------
@@ -261,18 +300,21 @@ def test_matrix_pipeline_batch_transcode_utf32():
         docs[i, : len(d)] = d
         lens.append(len(d))
     lens = np.asarray(lens, np.int32)
-    for strategy in ("packed", "vmap"):
-        res = pipeline.batch_transcode(docs, lens, in_encoding="utf8",
-                                       out_encoding="utf32",
-                                       strategy=strategy)
-        assert res.buffer.shape == (3, L)
-        for i in range(3):
-            text = bytes(docs[i, : lens[i]]).decode("utf-8")
-            assert int(res.status[i]) == -1, (strategy, i)
-            assert int(res.count[i]) == len(text), (strategy, i)
-            assert np.array_equal(
-                np.asarray(res.buffer[i])[: len(text)].astype(np.int64),
-                np.array([ord(c) for c in text], np.int64)), (strategy, i)
+    res = pipeline.batch_transcode(docs, lens, in_encoding="utf8",
+                                   out_encoding="utf32")
+    assert res.buffer.shape == (3, L)
+    for i in range(3):
+        # The packed batch against the per-document transcode and CPython.
+        one = tc.transcode(jnp.asarray(docs[i]), "utf32", src_format="utf8",
+                           n_valid=int(lens[i]))
+        assert np.array_equal(np.asarray(res.buffer[i]),
+                              np.asarray(one.buffer)), i
+        text = bytes(docs[i, : lens[i]]).decode("utf-8")
+        assert int(res.status[i]) == int(one.status) == -1, i
+        assert int(res.count[i]) == int(one.count) == len(text), i
+        assert np.array_equal(
+            np.asarray(res.buffer[i])[: len(text)].astype(np.int64),
+            np.array([ord(c) for c in text], np.int64)), i
 
 
 def test_matrix_pipeline_emits_codepoints_on_device():

@@ -22,6 +22,7 @@ from repro.core import transcode as tc
 from repro.data import synthetic
 from repro.kernels import onepass_transcode as op
 from repro.kernels import stages
+from repro.kernels import window
 
 BLOCK = stages.BLOCK
 N = 3 * op.G_MAX * BLOCK               # three whole blocks of units
@@ -67,7 +68,7 @@ def _run(doc):
 
 
 def test_the_call_runs_blocks_of_g_max_tiles():
-    assert op.tiles_per_step(N) == op.G_MAX
+    assert window.tiles_per_step(N, op.G_MAX) == op.G_MAX
     assert N // BLOCK == 3 * op.G_MAX
 
 

@@ -1,18 +1,20 @@
 """Ragged packed-batch transcode: packing layout, device ownership map,
-bit-identity with the per-document fused transcoder, batch entry points
-and the bounded per-capacity vmap cache."""
+bit-identity with the per-document block-parallel reference, and the
+batch entry points."""
+
+import functools
 
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from repro.core import packing
 from repro.core import transcode as tc
 from repro.data import pipeline, synthetic
-from repro.kernels import fused_transcode as ft
-from repro.kernels import onepass_transcode as op
 from repro.kernels import ragged_transcode as rt
+from repro.kernels import window
 
 TILE = packing.TILE
 
@@ -107,11 +109,18 @@ def test_tile_ownership_trailing_pad_tile_is_dead():
 
 
 # ---------------------------------------------------------------------------
-# Bit-identity with the per-document fused transcoder
+# Bit-identity with the per-document block-parallel reference
+
+
+@functools.partial(jax.jit, static_argnames=("src", "dst", "errors"))
+def _reference(buf, n, src, dst, errors):
+    """One document through the pure-jnp block-parallel reference."""
+    return tc.transcode(buf, dst, src_format=src, n_valid=n,
+                        strategy="blockparallel", errors=errors)
 
 
 def _assert_doc_equal(res, d, single, span):
-    """Ragged doc d must reproduce the single-doc fused TranscodeResult:
+    """Ragged doc d must reproduce the single-doc TranscodeResult:
     same count, same status, same buffer prefix (the single-doc buffer is
     capacity-clamped, so compare min(count, span) elements)."""
     assert int(res.counts[d]) == int(single.count), d
@@ -124,7 +133,7 @@ def _assert_doc_equal(res, d, single, span):
 
 
 @pytest.mark.parametrize("errors", ["strict", "replace"])
-def test_ragged_utf8_matches_per_doc_fused(errors):
+def test_ragged_utf8_matches_per_doc_blockparallel(errors):
     docs = _docs_mixed()
     pk = packing.pack_documents(docs)
     res = tc.ragged_utf8_to_utf16(pk.data, pk.offsets, pk.lengths,
@@ -136,12 +145,12 @@ def test_ragged_utf8_matches_per_doc_fused(errors):
         n = len(doc)
         buf = np.zeros(max(n, 1), np.uint8)
         buf[:n] = doc
-        single = ft.utf8_to_utf16_fused(jnp.asarray(buf), n, errors=errors)
+        single = _reference(buf, n, "utf8", "utf16", errors)
         _assert_doc_equal(res, d, single, max(n, 1))
 
 
 @pytest.mark.parametrize("errors", ["strict", "replace"])
-def test_ragged_utf16_matches_per_doc_fused(errors):
+def test_ragged_utf16_matches_per_doc_blockparallel(errors):
     docs = [
         synthetic.utf16_units("korean", 400, seed=1),
         np.zeros(0, np.uint16),
@@ -159,7 +168,7 @@ def test_ragged_utf16_matches_per_doc_fused(errors):
         n = len(doc)
         buf = np.zeros(max(n, 1), np.uint16)
         buf[:n] = doc
-        single = ft.utf16_to_utf8_fused(jnp.asarray(buf), n, errors=errors)
+        single = _reference(buf, n, "utf16", "utf8", errors)
         _assert_doc_equal(res, d, single, 3 * max(n, 1))
 
 
@@ -223,10 +232,8 @@ def test_ragged_rejects_malformed_batch_args():
 
 
 def test_ragged_single_launch_per_pass_jaxpr():
-    """The whole batch must transcode in ONE launch under the default
-    (one-pass) strategy — and in ONE count + ONE write launch under the
-    two-pass fused reference — vs one pair per document under vmap."""
-    import jax
+    """The whole batch must transcode in ONE launch, and scan in ONE
+    launch, however many documents it holds."""
     from _jaxpr import pallas_eqns
     pk = packing.pack_documents(_docs_mixed())
     args = (jnp.asarray(pk.data), jnp.asarray(pk.offsets),
@@ -234,10 +241,6 @@ def test_ragged_single_launch_per_pass_jaxpr():
     jaxpr = jax.make_jaxpr(
         lambda d, o, l: tc.ragged_utf8_to_utf16(d, o, l))(*args).jaxpr
     assert len(pallas_eqns(jaxpr)) == 1      # one-pass, batch-wide
-    jaxpr_fused = jax.make_jaxpr(
-        lambda d, o, l: tc.ragged_utf8_to_utf16(
-            d, o, l, strategy="fused"))(*args).jaxpr
-    assert len(pallas_eqns(jaxpr_fused)) == 2  # count + write, batch-wide
     jaxpr_scan = jax.make_jaxpr(
         lambda d, o, l: tc.ragged_scan_utf8(d, o, l))(*args).jaxpr
     assert len(pallas_eqns(jaxpr_scan)) == 1  # count pass only
@@ -257,7 +260,7 @@ _MALFORMED = [b"\x80", b"\xff", b"\xc3", b"\xe4\xb8", b"\xf0\x9f\x92",
 
 def _edge():
     """Tiles per block of an NT-tile batch: the first block edge."""
-    g = op.tiles_per_step(NT * TILE, op.RAGGED_G_MAX)
+    g = window.tiles_per_step(NT * TILE, rt.RAGGED_G_MAX)
     assert 1 < g < NT
     return g
 
@@ -277,19 +280,26 @@ def _splice(raw, at, piece):
 
 
 def _check_block_batch(docs, errors):
-    """One NT-tile batch: the one-pass kernel bit-exact against
-    strategy="fused", and every document against CPython."""
+    """One NT-tile batch: every document bit-exact against the
+    block-parallel reference on that document alone, and against
+    CPython."""
     pk = packing.pack_documents(docs, dtype=np.uint8, pad_to_docs=NDOCS)
     assert pk.data.shape[0] <= NT * TILE
     data = np.zeros(NT * TILE, np.uint8)
     data[:pk.data.shape[0]] = pk.data
-    one, ref = (rt.transcode_ragged(data, pk.offsets, pk.lengths,
-                                    src="utf8", dst="utf16", errors=errors,
-                                    strategy=s)
-                for s in ("onepass", "fused"))
-    for field, a, b in zip(one._fields, one, ref):
-        assert np.array_equal(np.asarray(a), np.asarray(b)), field
+    one = rt.transcode_ragged(data, pk.offsets, pk.lengths, src="utf8",
+                              dst="utf16", errors=errors)
+    assert np.array_equal(np.asarray(one.offsets),
+                          np.concatenate([[0], np.cumsum(one.counts)]))
     buf = np.asarray(one.buffer)
+    assert not buf[int(one.offsets[-1]):].any()
+    for d, raw in enumerate(docs):
+        # One buffer shape for every document, so the cases share the
+        # reference's programs.
+        doc = np.zeros(NT * TILE, np.uint8)
+        doc[:len(raw)] = np.frombuffer(raw, np.uint8)
+        _assert_doc_equal(one, d, _reference(doc, len(raw), "utf8",
+                                             "utf16", errors), NT * TILE)
     for d, raw in enumerate(docs):
         try:
             want, pos = raw.decode("utf-8").encode("utf-16-le"), -1
@@ -394,10 +404,18 @@ def test_ragged_blocks_fuzz(errors):
 
 
 # ---------------------------------------------------------------------------
-# Batch entry points (strategy="packed" vs the vmap reference)
+# Batch entry points (strategy="packed" vs a per-document transcode loop)
 
 
-def test_batch_entries_packed_equals_vmap():
+def _per_doc(docs, lens, src, dst):
+    """The batch contract, one ``transcode`` call per document."""
+    rows = [tc.transcode(jnp.asarray(docs[i]), dst, src_format=src,
+                         n_valid=int(lens[i])) for i in range(len(docs))]
+    return tuple(np.stack([np.asarray(r[k]) for r in rows])
+                 for k in range(3))
+
+
+def test_batch_entries_packed_equals_per_doc_transcode():
     L = 1536
     docs = np.zeros((4, L), np.uint8)
     lens = []
@@ -407,11 +425,11 @@ def test_batch_entries_packed_equals_vmap():
         lens.append(len(d))
     lens = np.asarray(lens, np.int32)
     pk = pipeline.batch_utf8_to_utf16(docs, lens)              # packed
-    vm = pipeline.batch_utf8_to_utf16(docs, lens, strategy="vmap")
-    assert pk.buffer.shape == vm.buffer.shape == (4, L)
-    assert np.array_equal(np.asarray(pk.buffer), np.asarray(vm.buffer))
-    assert np.array_equal(np.asarray(pk.count), np.asarray(vm.count))
-    assert np.array_equal(np.asarray(pk.status), np.asarray(vm.status))
+    buffer, count, status = _per_doc(docs, lens, "utf8", "utf16")
+    assert pk.buffer.shape == buffer.shape == (4, L)
+    assert np.array_equal(np.asarray(pk.buffer), buffer)
+    assert np.array_equal(np.asarray(pk.count), count)
+    assert np.array_equal(np.asarray(pk.status), status)
 
     units = np.zeros((2, 1024), np.uint16)
     ulens = []
@@ -421,11 +439,11 @@ def test_batch_entries_packed_equals_vmap():
         ulens.append(len(d))
     ulens = np.asarray(ulens, np.int32)
     pk = pipeline.batch_utf16_to_utf8(units, ulens)
-    vm = pipeline.batch_utf16_to_utf8(units, ulens, strategy="vmap")
-    assert pk.buffer.shape == vm.buffer.shape == (2, 3 * 1024)
-    assert np.array_equal(np.asarray(pk.buffer), np.asarray(vm.buffer))
-    assert np.array_equal(np.asarray(pk.count), np.asarray(vm.count))
-    assert np.array_equal(np.asarray(pk.status), np.asarray(vm.status))
+    buffer, count, status = _per_doc(units, ulens, "utf16", "utf8")
+    assert pk.buffer.shape == buffer.shape == (2, 3 * 1024)
+    assert np.array_equal(np.asarray(pk.buffer), buffer)
+    assert np.array_equal(np.asarray(pk.count), count)
+    assert np.array_equal(np.asarray(pk.status), status)
 
 
 def test_batch_entries_replace_policy_threads_through():
@@ -438,31 +456,3 @@ def test_batch_entries_replace_policy_threads_through():
         b"a\xffb".decode("utf-8", "replace").encode("utf-16-le"), np.uint16)
     assert np.array_equal(np.asarray(res.buffer[0])[:3], want0)
     assert int(res.status[0]) == 1 and int(res.status[1]) == -1
-
-
-# ---------------------------------------------------------------------------
-# _BATCH_CACHE: keyed per-capacity, LRU-bounded
-
-
-def test_batch_cache_keyed_per_capacity_and_bounded():
-    pipeline._BATCH_CACHE.clear()
-    f1 = pipeline._batched("utf8", "utf16", "fused", True, "strict", 1024)
-    f2 = pipeline._batched("utf8", "utf16", "fused", True, "strict", 1024)
-    assert f1 is f2                       # same capacity -> cached callable
-    f3 = pipeline._batched("utf8", "utf16", "fused", True, "strict", 2048)
-    assert f3 is not f1                   # capacity is part of the key
-    assert len(pipeline._BATCH_CACHE) == 2
-    for cap in range(3 * pipeline._BATCH_CACHE_MAX):
-        pipeline._batched("utf8", "utf16", "fused", True, "strict", 4096 + cap)
-    assert len(pipeline._BATCH_CACHE) <= pipeline._BATCH_CACHE_MAX
-
-
-def test_batch_cache_lru_keeps_hot_entries():
-    pipeline._BATCH_CACHE.clear()
-    hot = pipeline._batched("utf8", "utf16", "fused", True, "strict", 1024)
-    for cap in range(pipeline._BATCH_CACHE_MAX - 1):
-        pipeline._batched("utf8", "utf16", "fused", True, "strict", 2048 + cap)
-    # Touch the hot entry, then overflow: the hot entry must survive.
-    assert pipeline._batched("utf8", "utf16", "fused", True, "strict", 1024) is hot
-    pipeline._batched("utf8", "utf16", "fused", True, "strict", 9999)
-    assert ("utf8", "utf16", "fused", True, "strict", 1024) in pipeline._BATCH_CACHE

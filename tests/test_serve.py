@@ -87,7 +87,7 @@ def test_lone_surrogate_utf16_replace_served(engine):
 
 def test_valid_utf16_prompt_equals_utf8_prompt(engine):
     """A valid UTF-16LE prompt tokenizes identically to its UTF-8 twin
-    (the fused transcode is the ingress tokenizer's source)."""
+    (the device transcode is the ingress tokenizer's source)."""
     s = "hé🎉"
     r8 = engine.serve([Request(s.encode("utf-8"))])[0]
     r16 = engine.serve([Request(s.encode("utf-16-le"),

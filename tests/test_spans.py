@@ -85,14 +85,6 @@ def test_ragged_transcode_and_packing_write_their_spans(tmp_path):
     assert spans.TRANSCODE not in {e[0] for e in evs}
 
 
-def test_ragged_dispatch_span_covers_the_two_launch_strategy(tmp_path):
-    pk = packing.pack_documents([np.frombuffer(b"abc", np.uint8)])
-    evs = _traced(tmp_path, lambda: tc.ragged_transcode(
-        pk.data, pk.offsets, pk.lengths, strategy="fused"))
-    assert _inside(_only(evs, spans.RAGGED_DISPATCH),
-                   _only(evs, spans.RAGGED_TRANSCODE))
-
-
 def test_unknown_ragged_strategy_is_still_refused():
     pk = packing.pack_documents([np.frombuffer(b"abc", np.uint8)])
     with pytest.raises(ValueError, match="unknown strategy"):

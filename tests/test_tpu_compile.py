@@ -22,12 +22,12 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels import fused_transcode as ft
 from repro.kernels import onepass_transcode as op
 from repro.kernels import ragged_transcode as rt
 from repro.kernels import runtime
+from repro.kernels import window
 
-N_CHARS = 1 << 17      # benchmarks/transcode_bench.py N_CHARS
+N_CHARS = 1 << 17      # chip_smoke.py N_CHARS
 N_DOCS = 64            # chip_smoke.py packed batch
 DTYPE = {"utf8": jnp.uint8, "utf16": jnp.uint16}
 # Worst-case stored units for N_CHARS characters (4-byte UTF-8, 2-unit
@@ -85,7 +85,7 @@ def test_onepass_bulk_cell_shape_compiles_for_v5e(one_chip):
     validated, which runs blocks of ``G_MAX`` tiles per grid step: the
     block's vregs, VMEM buffers and SMEM carry fit the chip."""
     n = 4 << 20
-    assert op.tiles_per_step(n) == op.G_MAX
+    assert window.tiles_per_step(n, op.G_MAX) == op.G_MAX
     text = _compile_text(
         lambda x: op.transcode_onepass(x, src="utf8", dst="utf16",
                                        validate=True, errors="strict",
@@ -100,7 +100,7 @@ def test_onepass_utf16_bulk_cell_shape_compiles_for_v5e(one_chip):
     windows of three bytes per unit, with their block buffer, fit the
     chip."""
     n = 2 << 20
-    assert op.tiles_per_step(n) == op.G_MAX
+    assert window.tiles_per_step(n, op.G_MAX) == op.G_MAX
     text = _compile_text(
         lambda x: op.transcode_onepass(x, src="utf16", dst="utf8",
                                        validate=True, errors="strict",
@@ -112,7 +112,7 @@ def test_onepass_utf16_bulk_cell_shape_compiles_for_v5e(one_chip):
 @pytest.mark.parametrize("src,dst", PAIRS)
 def test_scan_compiles_for_v5e(one_chip, src, dst):
     text = _compile_text(
-        lambda x: ft.scan_fused(x, src=src, dst=dst, interpret=False),
+        lambda x: op.scan_onepass(x, src=src, dst=dst, interpret=False),
         _doc(src, one_chip))
     assert "tpu_custom_call" in text
 
@@ -134,7 +134,7 @@ def test_ragged_ingest_cell_shape_compiles_for_v5e(one_chip):
     its own document: the block's vregs, VMEM buffers and six per-tile
     SMEM vectors fit the chip."""
     n, docs = 1 << 20, 1024
-    assert op.tiles_per_step(n, op.RAGGED_G_MAX) == op.RAGGED_G_MAX
+    assert window.tiles_per_step(n, rt.RAGGED_G_MAX) == rt.RAGGED_G_MAX
     text = _compile_text(
         lambda d, o, l: rt.transcode_ragged(d, o, l, src="utf8",
                                             dst="utf16", validate=True,
@@ -159,9 +159,9 @@ def test_ragged_scan_compiles_for_v5e(one_chip, src, dst):
 # Launch-size limits: the largest input one launch takes compiles, and one
 # tile more is refused before compiling, with the limit in the message.
 
-BLOCK = ft.BLOCK
+BLOCK = window.BLOCK
 # Ragged launches keep six int32 vectors per tile in SMEM.
-RAGGED_MAX_TILES = ft.SMEM_BUDGET // (4 * 6)
+RAGGED_MAX_TILES = window.SMEM_BUDGET // (4 * 6)
 
 
 def _packed(n, sharding=None):

@@ -1,6 +1,8 @@
-"""Fused two-pass pipeline: strategy equivalence, edge cases, and
-HBM-traffic shape checks (narrow ingress, no full-capacity int32 between
-decode and compaction)."""
+"""The transcode kernels (``transcode_onepass`` / ``scan_onepass``):
+equivalence with the pure-jnp ``blockparallel`` and paper ``windowed``
+references and CPython ``codecs``, edge cases, and HBM-traffic shape
+checks (narrow ingress, no full-capacity int32 between decode and
+compaction)."""
 
 import numpy as np
 import pytest
@@ -11,11 +13,21 @@ from _jaxpr import iter_eqns, pallas_eqns
 
 from repro.core import transcode as tc
 from repro.data import pipeline, synthetic
-from repro.kernels import fused_transcode as ft
+from repro.kernels import onepass_transcode as op
 from repro.kernels import ops, runtime
 
 LIPSUM_LANGS = ["arabic", "chinese", "emoji", "hebrew", "hindi",
                 "japanese", "korean", "latin", "russian"]
+
+
+def _u8(x, n_valid=None, **kw):
+    """UTF-8 -> UTF-16 through the kernel."""
+    return op.transcode_onepass(x, n_valid, src="utf8", dst="utf16", **kw)
+
+
+def _u16(x, n_valid=None, **kw):
+    """UTF-16 -> UTF-8 through the kernel."""
+    return op.transcode_onepass(x, n_valid, src="utf16", dst="utf8", **kw)
 
 
 def _utf8(lang, n_chars, seed=0):
@@ -54,11 +66,11 @@ def _py_err_start16(units: np.ndarray):
 
 
 @pytest.mark.parametrize("lang", LIPSUM_LANGS)
-def test_fused_equals_blockparallel_and_windowed_utf8_to_utf16(lang):
+def test_kernel_equals_blockparallel_and_windowed_utf8_to_utf16(lang):
     b = _utf8(lang, 1200, seed=11)
     n = len(b)
     got_f = _unpack(tc.transcode_utf8_to_utf16(
-        jnp.asarray(b), n, strategy="fused"))
+        jnp.asarray(b), n, strategy="onepass"))
     got_b = _unpack(tc.transcode_utf8_to_utf16(
         jnp.asarray(b.astype(np.int32)), n, strategy="blockparallel"))
     got_w = _unpack(tc.transcode_utf8_to_utf16(
@@ -74,11 +86,11 @@ def test_fused_equals_blockparallel_and_windowed_utf8_to_utf16(lang):
 
 
 @pytest.mark.parametrize("lang", LIPSUM_LANGS)
-def test_fused_equals_blockparallel_and_windowed_utf16_to_utf8(lang):
+def test_kernel_equals_blockparallel_and_windowed_utf16_to_utf8(lang):
     u = _utf16(lang, 1200, seed=11)
     n = len(u)
     got_f = _unpack(tc.transcode_utf16_to_utf8(
-        jnp.asarray(u), n, strategy="fused"))
+        jnp.asarray(u), n, strategy="onepass"))
     got_b = _unpack(tc.transcode_utf16_to_utf8(
         jnp.asarray(u.astype(np.int32)), n, strategy="blockparallel"))
     got_w = _unpack(tc.transcode_utf16_to_utf8(
@@ -96,7 +108,7 @@ def test_fused_equals_blockparallel_and_windowed_utf16_to_utf8(lang):
 # Property test: random valid + mutated-invalid streams
 
 
-def test_fused_equals_blockparallel_on_mutated_streams():
+def test_kernel_equals_blockparallel_on_mutated_streams():
     rng = np.random.default_rng(7)
     langs = ["latin", "arabic", "chinese", "emoji"]
     fixed = 1536  # fixed buffer so all cases share one compilation
@@ -109,16 +121,16 @@ def test_fused_equals_blockparallel_on_mutated_streams():
             k = rng.integers(1, 4)
             buf[rng.integers(0, max(n, 1), k)] = rng.integers(0, 256, k)
         want_pos = _py_err_start(bytes(buf[:n]))
-        got_f = _unpack(ft.utf8_to_utf16_fused(jnp.asarray(buf), n))
+        got_f = _unpack(_u8(jnp.asarray(buf), n))
         got_b = _unpack(tc.utf8_to_utf16(
             jnp.asarray(buf.astype(np.int32)), n))
         assert got_f[1] == got_b[1], trial
         assert np.array_equal(got_f[0], got_b[0]), trial
-        # single-scan status: fused == blockparallel == Python exc.start
+        # single-scan status: kernel == blockparallel == Python exc.start
         assert got_f[2] == got_b[2] == want_pos, trial
 
 
-def test_fused_equals_blockparallel_on_mutated_utf16_streams():
+def test_kernel_equals_blockparallel_on_mutated_utf16_streams():
     rng = np.random.default_rng(9)
     fixed = 1280
     for trial in range(16):
@@ -132,7 +144,7 @@ def test_fused_equals_blockparallel_on_mutated_utf16_streams():
             buf[rng.integers(0, max(n, 1), k)] = \
                 rng.integers(0, 1 << 16, k)
         want_pos = _py_err_start16(buf[:n])
-        got_f = _unpack(ft.utf16_to_utf8_fused(jnp.asarray(buf), n))
+        got_f = _unpack(_u16(jnp.asarray(buf), n))
         got_b = _unpack(tc.utf16_to_utf8(
             jnp.asarray(buf.astype(np.int32)), n))
         assert got_f[1] == got_b[1], trial
@@ -144,13 +156,13 @@ def test_fused_equals_blockparallel_on_mutated_utf16_streams():
 # Edge cases
 
 
-def test_fused_speculative_worst_case_stage_width():
+def test_kernel_speculative_worst_case_stage_width():
     """Invalid input dense in 4-byte leads makes EVERY byte of a tile a
     speculative 2-unit lead (2*BLOCK units per tile) — the per-tile stage
     must absorb that or base offsets desynchronize from blockparallel."""
     b = np.concatenate([np.full(1024, 0xF4, np.uint8),
                         np.full(1024, 0xF1, np.uint8)])
-    got_f = _unpack(ft.utf8_to_utf16_fused(jnp.asarray(b), len(b)))
+    got_f = _unpack(_u8(jnp.asarray(b), len(b)))
     got_b = _unpack(tc.utf8_to_utf16(jnp.asarray(b.astype(np.int32)),
                                      len(b)))
     assert got_f[1] == got_b[1]
@@ -159,7 +171,7 @@ def test_fused_speculative_worst_case_stage_width():
     # UTF-16 side: every unit a speculative 3-byte lane (valid stream of
     # U+E000) exactly fills the 3*BLOCK stage.
     u = np.full(2048, 0xE000, np.uint16)
-    got_f = _unpack(ft.utf16_to_utf8_fused(jnp.asarray(u), len(u)))
+    got_f = _unpack(_u16(jnp.asarray(u), len(u)))
     got_b = _unpack(tc.utf16_to_utf8(jnp.asarray(u.astype(np.int32)),
                                      len(u)))
     assert got_f[1] == got_b[1] == 3 * 2048
@@ -170,7 +182,7 @@ def test_fused_speculative_worst_case_stage_width():
     u = np.concatenate([np.full(1023, 0xE000, np.uint16),
                         np.asarray([0xD800, 0xDC00], np.uint16),
                         np.full(1023, 0x41, np.uint16)])
-    got_f = _unpack(ft.utf16_to_utf8_fused(jnp.asarray(u), len(u)))
+    got_f = _unpack(_u16(jnp.asarray(u), len(u)))
     got_b = _unpack(tc.utf16_to_utf8(jnp.asarray(u.astype(np.int32)),
                                      len(u)))
     want = np.frombuffer(
@@ -181,7 +193,7 @@ def test_fused_speculative_worst_case_stage_width():
     assert got_f[2] == got_b[2] == -1
     # and the unpaired-high-surrogate flood (mixed 3-byte/4-byte lanes)
     u = np.full(2048, 0xD800, np.uint16)
-    got_f = _unpack(ft.utf16_to_utf8_fused(jnp.asarray(u), len(u)))
+    got_f = _unpack(_u16(jnp.asarray(u), len(u)))
     got_b = _unpack(tc.utf16_to_utf8(jnp.asarray(u.astype(np.int32)),
                                      len(u)))
     assert got_f[1] == got_b[1]
@@ -189,41 +201,41 @@ def test_fused_speculative_worst_case_stage_width():
     assert got_f[2] == got_b[2] == 0
 
 
-def test_fused_zero_length():
-    out, cnt, status = ft.utf8_to_utf16_fused(jnp.zeros((0,), jnp.uint8), 0)
+def test_kernel_zero_length():
+    out, cnt, status = _u8(jnp.zeros((0,), jnp.uint8), 0)
     assert out.shape == (0,) and int(cnt) == 0 and int(status) == -1
-    out, cnt, status = ft.utf16_to_utf8_fused(jnp.zeros((0,), jnp.uint16), 0)
+    out, cnt, status = _u16(jnp.zeros((0,), jnp.uint16), 0)
     assert out.shape == (0,) and int(cnt) == 0 and int(status) == -1
 
 
-def test_fused_n_valid_zero():
+def test_kernel_n_valid_zero():
     b = jnp.asarray(np.full(64, 0xFF, np.uint8))  # garbage beyond n
-    out, cnt, status = ft.utf8_to_utf16_fused(b, 0)
+    out, cnt, status = _u8(b, 0)
     assert int(cnt) == 0 and int(status) == -1
 
 
-def test_fused_tile_aligned_trailing_truncation():
+def test_kernel_tile_aligned_trailing_truncation():
     b = np.full(2048, 0x41, np.uint8)
     b[-1] = 0xC3  # lead byte truncated exactly at a tile boundary
-    _, _, status = ft.utf8_to_utf16_fused(jnp.asarray(b), 2048)
+    _, _, status = _u8(jnp.asarray(b), 2048)
     assert int(status) == 2047  # located at the truncated lead
     u = np.full(1024, 0x41, np.uint16)
     u[-1] = 0xD800  # lone high surrogate at the tile boundary
-    _, _, status = ft.utf16_to_utf8_fused(jnp.asarray(u), 1024)
+    _, _, status = _u16(jnp.asarray(u), 1024)
     assert int(status) == 1023
 
 
-def test_fused_cross_tile_characters():
+def test_kernel_cross_tile_characters():
     s = "A" * 1022 + "🎉" + "B" * 100  # 4-byte char straddles the boundary
     b = np.frombuffer(s.encode("utf-8"), np.uint8)
-    out, cnt, status = ft.utf8_to_utf16_fused(jnp.asarray(b), len(b))
+    out, cnt, status = _u8(jnp.asarray(b), len(b))
     want = np.frombuffer(s.encode("utf-16-le"), np.uint16)
     assert int(status) == -1
     assert np.array_equal(np.asarray(out)[: int(cnt)], want)
 
     u = np.full(2048, 0x41, np.int32)
     u[1023], u[1024] = 0xD83C, 0xDF89  # pair straddles the boundary
-    out, cnt, status = ft.utf16_to_utf8_fused(jnp.asarray(u), 2048)
+    out, cnt, status = _u16(jnp.asarray(u), 2048)
     want = np.frombuffer(
         u.astype(np.uint16).tobytes().decode("utf-16-le").encode("utf-8"),
         np.uint8)
@@ -231,11 +243,11 @@ def test_fused_cross_tile_characters():
     assert np.array_equal(np.asarray(out)[: int(cnt)], want)
 
 
-def test_fused_ascii_fastpath_agrees_with_general():
+def test_kernel_ascii_fastpath_agrees_with_general():
     b = _utf8("latin", 500, seed=3)
     n = len(b)
-    fast = _unpack(ft.utf8_to_utf16_fused(jnp.asarray(b), n))
-    slow = _unpack(ft.utf8_to_utf16_fused(jnp.asarray(b), n,
+    fast = _unpack(_u8(jnp.asarray(b), n))
+    slow = _unpack(_u8(jnp.asarray(b), n,
                                           ascii_fastpath=False))
     assert fast[1] == slow[1] and fast[2] == slow[2]
     assert np.array_equal(fast[0], slow[0])
@@ -246,14 +258,14 @@ def test_fused_ascii_fastpath_agrees_with_general():
 # full-capacity int32 between decode and compaction)
 
 
-def test_fused_utf8_jaxpr_has_narrow_io_and_no_global_scatter():
+def test_kernel_utf8_jaxpr_has_narrow_io_and_no_global_scatter():
     cap = 4096
     b = jnp.zeros((cap,), jnp.uint8)
     jaxpr = jax.make_jaxpr(
-        lambda x: ft.utf8_to_utf16_fused(x, cap - 5, ascii_fastpath=False)
+        lambda x: _u8(x, cap - 5, ascii_fastpath=False)
     )(b).jaxpr
     kernels = pallas_eqns(jaxpr)
-    assert len(kernels) == 2  # count pass + write pass
+    assert len(kernels) == 1  # one launch: count and write off one decode
     for eqn in kernels:
         # Ingress <= 1 byte/element: every large operand is uint8.
         for v in eqn.invars:
@@ -269,14 +281,14 @@ def test_fused_utf8_jaxpr_has_narrow_io_and_no_global_scatter():
     assert not any("scatter" in n for n in names), names
 
 
-def test_fused_utf16_jaxpr_has_narrow_io_and_no_global_scatter():
+def test_kernel_utf16_jaxpr_has_narrow_io_and_no_global_scatter():
     cap_in = 2048
     u = jnp.zeros((cap_in,), jnp.uint16)
     jaxpr = jax.make_jaxpr(
-        lambda x: ft.utf16_to_utf8_fused(x, cap_in - 5, ascii_fastpath=False)
+        lambda x: _u16(x, cap_in - 5, ascii_fastpath=False)
     )(u).jaxpr
     kernels = pallas_eqns(jaxpr)
-    assert len(kernels) == 2
+    assert len(kernels) == 1
     for eqn in kernels:
         for v in eqn.invars:
             if v.aval.size >= cap_in:
@@ -303,13 +315,13 @@ def test_blockparallel_kernel_path_is_the_contrast():
 
 
 # ---------------------------------------------------------------------------
-# Validation fusion (acceptance): strategy="fused" with validation makes
-# exactly ONE scan over the input bytes per pass — no standalone validate
-# read — and the first_error_index matches Python's bytes.decode position.
+# Validation fusion (acceptance): the kernel with validation makes exactly
+# ONE scan over the input bytes — no standalone validate read — and the
+# first_error_index matches Python's bytes.decode position.
 
 
-def test_fused_validation_is_single_scan_jaxpr():
-    """Validation must ride along with the count pass: turning validate on
+def test_kernel_validation_is_single_scan_jaxpr():
+    """Validation must ride along with the decode: turning validate on
     adds NO kernel launch and NO out-of-kernel read of the input bytes
     (the old standalone validate_kl pass showed up as full-capacity
     gathers outside pallas)."""
@@ -318,12 +330,12 @@ def test_fused_validation_is_single_scan_jaxpr():
     jaxprs = {}
     for validate in (True, False):
         jaxprs[validate] = jax.make_jaxpr(
-            lambda x, v=validate: ft.utf8_to_utf16_fused(
+            lambda x, v=validate: _u8(
                 x, cap - 5, validate=v, ascii_fastpath=False))(b).jaxpr
     for validate, jaxpr in jaxprs.items():
         kernels = pallas_eqns(jaxpr)
-        # count pass + write pass, nothing else — validation adds no scan.
-        assert len(kernels) == 2, (validate, len(kernels))
+        # one launch, nothing else — validation adds no scan.
+        assert len(kernels) == 1, (validate, len(kernels))
         # No out-of-kernel gather touches a capacity-sized operand (the
         # nibble-table lookups run inside the kernel).
         for eqn in iter_eqns(jaxpr):
@@ -332,15 +344,15 @@ def test_fused_validation_is_single_scan_jaxpr():
                     (validate, eqn)
 
 
-def test_fused_scan_is_count_pass_only():
-    """scan_utf8/scan_utf16: validation + capacity in ONE pallas call."""
+def test_kernel_scan_is_count_pass_only():
+    """scan_onepass: validation + capacity in ONE pallas call."""
     cap = 2048
     jaxpr = jax.make_jaxpr(
-        lambda x: ft.utf8_scan_fused(x, cap - 3))(
+        lambda x: op.scan_onepass(x, cap - 3, src="utf8", dst="utf16"))(
             jnp.zeros((cap,), jnp.uint8)).jaxpr
     assert len(pallas_eqns(jaxpr)) == 1
     jaxpr16 = jax.make_jaxpr(
-        lambda x: ft.utf16_scan_fused(x, cap - 3))(
+        lambda x: op.scan_onepass(x, cap - 3, src="utf16", dst="utf8"))(
             jnp.zeros((cap,), jnp.uint16)).jaxpr
     assert len(pallas_eqns(jaxpr16)) == 1
 
@@ -370,9 +382,10 @@ def test_first_error_index_matches_python_on_fuzzed_corpus():
             n = int(rng.integers(1, 64))
             buf[:n] = rng.choice(adversarial, n)
         want = _py_err_start(bytes(buf[:n]))
-        _, _, status = ft.utf8_to_utf16_fused(jnp.asarray(buf), n)
+        _, _, status = _u8(jnp.asarray(buf), n)
         assert int(status) == want, (trial, bytes(buf[:n])[:20])
-        count, sstatus = ft.utf8_scan_fused(jnp.asarray(buf), n)
+        count, sstatus = op.scan_onepass(jnp.asarray(buf), n, src="utf8",
+                                          dst="utf16")
         assert int(sstatus) == want, trial
         bcount, bstatus = tc.scan_utf8(jnp.asarray(buf), n,
                                        strategy="blockparallel")
@@ -391,7 +404,8 @@ def test_utf16_scan_status_matches_python():
             want = -1
         except UnicodeDecodeError as e:
             want = e.start // 2
-        count, status = ft.utf16_scan_fused(jnp.asarray(buf), n)
+        count, status = op.scan_onepass(jnp.asarray(buf), n, src="utf16",
+                                       dst="utf8")
         assert int(status) == want, trial
         bcount, bstatus = tc.scan_utf16(jnp.asarray(buf), n,
                                         strategy="blockparallel")
@@ -402,7 +416,7 @@ def test_utf16_scan_status_matches_python():
 # errors="replace": U+FFFD per maximal subpart, CPython semantics
 
 
-def test_fused_replace_matches_python_utf8():
+def test_kernel_replace_matches_python_utf8():
     rng = np.random.default_rng(5)
     fixed = 1536
     for trial in range(20):
@@ -420,7 +434,7 @@ def test_fused_replace_matches_python_utf8():
         want = np.frombuffer(
             bytes(buf[:n]).decode("utf-8", "replace").encode("utf-16-le"),
             np.uint16)
-        got_f = _unpack(ft.utf8_to_utf16_fused(jnp.asarray(buf), n,
+        got_f = _unpack(_u8(jnp.asarray(buf), n,
                                                errors="replace"))
         got_b = _unpack(tc.utf8_to_utf16(jnp.asarray(buf.astype(np.int32)),
                                          n, errors="replace"))
@@ -430,7 +444,7 @@ def test_fused_replace_matches_python_utf8():
         assert got_f[2] == got_b[2] == _py_err_start(bytes(buf[:n])), trial
 
 
-def test_fused_replace_matches_python_utf16():
+def test_kernel_replace_matches_python_utf16():
     rng = np.random.default_rng(23)
     fixed = 1280
     for trial in range(16):
@@ -448,7 +462,7 @@ def test_fused_replace_matches_python_utf16():
         want = np.frombuffer(
             buf[:n].tobytes().decode("utf-16-le", "replace").encode("utf-8"),
             np.uint8)
-        got_f = _unpack(ft.utf16_to_utf8_fused(jnp.asarray(buf), n,
+        got_f = _unpack(_u16(jnp.asarray(buf), n,
                                                errors="replace"))
         got_b = _unpack(tc.utf16_to_utf8(jnp.asarray(buf.astype(np.int32)),
                                          n, errors="replace"))
@@ -469,9 +483,9 @@ def test_error_location_and_replace_across_tile_boundary():
             buf = np.full(2048, 0x41, np.uint8)
             buf[pos: pos + len(probe)] = np.frombuffer(probe, np.uint8)
             raw = bytes(buf)
-            _, _, status = ft.utf8_to_utf16_fused(jnp.asarray(buf), 2048)
+            _, _, status = _u8(jnp.asarray(buf), 2048)
             assert int(status) == _py_err_start(raw), (probe, pos)
-            got = _unpack(ft.utf8_to_utf16_fused(jnp.asarray(buf), 2048,
+            got = _unpack(_u8(jnp.asarray(buf), 2048,
                                                  errors="replace"))
             want = np.frombuffer(
                 raw.decode("utf-8", "replace").encode("utf-16-le"),
@@ -482,8 +496,8 @@ def test_error_location_and_replace_across_tile_boundary():
 def test_replace_on_valid_input_equals_strict():
     b = _utf8("japanese", 800, seed=9)
     n = len(b)
-    strict = _unpack(ft.utf8_to_utf16_fused(jnp.asarray(b), n))
-    rep = _unpack(ft.utf8_to_utf16_fused(jnp.asarray(b), n,
+    strict = _unpack(_u8(jnp.asarray(b), n))
+    rep = _unpack(_u8(jnp.asarray(b), n,
                                          errors="replace"))
     assert strict[1] == rep[1] and strict[2] == rep[2] == -1
     assert np.array_equal(strict[0], rep[0])
@@ -492,7 +506,7 @@ def test_replace_on_valid_input_equals_strict():
 def test_unknown_errors_policy_rejected():
     b = jnp.zeros((8,), jnp.uint8)
     with pytest.raises(ValueError):
-        ft.utf8_to_utf16_fused(b, 8, errors="ignore")
+        _u8(b, 8, errors="ignore")
     with pytest.raises(ValueError):
         tc.transcode_utf8_to_utf16(b, 8, strategy="windowed",
                                    errors="replace")
@@ -515,7 +529,7 @@ def test_batched_entry_matches_per_doc():
     out, cnt, status = pipeline.batch_utf8_to_utf16(docs, lens)
     assert out.shape == (3, L)
     for i in range(3):
-        o, c, s = ft.utf8_to_utf16_fused(jnp.asarray(docs[i]), int(lens[i]))
+        o, c, s = _u8(jnp.asarray(docs[i]), int(lens[i]))
         assert int(cnt[i]) == int(c) and int(status[i]) == int(s)
         assert np.array_equal(np.asarray(out[i])[: int(c)],
                               np.asarray(o)[: int(c)])
@@ -529,7 +543,7 @@ def test_batched_entry_matches_per_doc():
     out, cnt, status = pipeline.batch_utf16_to_utf8(units, np.asarray(ulens))
     assert out.shape == (2, 3 * 1024)
     for i in range(2):
-        o, c, s = ft.utf16_to_utf8_fused(jnp.asarray(units[i]), ulens[i])
+        o, c, s = _u16(jnp.asarray(units[i]), ulens[i])
         assert int(cnt[i]) == int(c) and int(status[i]) == int(s)
         assert np.array_equal(np.asarray(out[i])[: int(c)],
                               np.asarray(o)[: int(c)])

@@ -105,7 +105,7 @@ def test_invalid_bytes_flagged_by_transcoder(raw):
 @given(st.binary(max_size=48))
 def test_replace_matches_python_utf8(raw):
     """Arbitrary bytes: errors='replace' output == Python's, byte-exact,
-    and the fused single-scan status equals the blockparallel one."""
+    and the onepass kernel's status equals the blockparallel one."""
     want = np.frombuffer(
         raw.decode("utf-8", "replace").encode("utf-16-le"), np.uint16)
     cap = 128  # fixed capacity: all examples share one compilation
@@ -116,7 +116,7 @@ def test_replace_matches_python_utf8(raw):
     got = np.asarray(out)[: int(cnt)].astype(np.uint16)
     assert np.array_equal(got, want), raw
     fout, fcnt, fstatus = tc.transcode_utf8_to_utf16(
-        jnp.asarray(buf), len(raw), strategy="fused", errors="replace")
+        jnp.asarray(buf), len(raw), strategy="onepass", errors="replace")
     assert int(fcnt) == int(cnt) and int(fstatus) == int(status), raw
     assert np.array_equal(np.asarray(fout)[: int(fcnt)], got), raw
 
@@ -136,7 +136,7 @@ def test_replace_matches_python_utf16(units):
     got = np.asarray(out)[: int(cnt)].astype(np.uint8)
     assert np.array_equal(got, want), units
     fout, fcnt, fstatus = tc.transcode_utf16_to_utf8(
-        jnp.asarray(buf), len(units), strategy="fused", errors="replace")
+        jnp.asarray(buf), len(units), strategy="onepass", errors="replace")
     assert int(fcnt) == int(cnt) and int(fstatus) == int(status), units
     assert np.array_equal(np.asarray(fout)[: int(fcnt)], got), units
 
